@@ -51,20 +51,16 @@ if __package__ in (None, ""):   # bare run: python benchmarks/placement_latency.
     except ModuleNotFoundError:
         sys.path.insert(0, str(_ROOT / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.endpoint import scaled_testbed
 from repro.core.engine import OnlineEngine
 from repro.core.scheduler import TaskSpec, auto_engine
 from repro.core.testbed import BASE_PROFILES, SEBS_FUNCTIONS
 from repro.core.predictor import TaskProfileStore
 
-try:
-    from repro.kernels.placement import ops as placement_ops
-except Exception:  # pragma: no cover - jax-less environment
-    placement_ops = None
-
 # fleet-size sweep: scaled_testbed multiplier -> 4/8/16/32 endpoints
 FLEET_SWEEP = (1, 2, 4, 8)
-ENGINES = ("delta", "soa") + (("jax",) if placement_ops is not None else ()) + ("auto",)
+ENGINES = ("delta", "soa", "jax", "auto")
 LONG_STREAM_TASKS = 16384
 
 
@@ -279,6 +275,7 @@ def _parse(argv):
 
 
 def _run_all(args):
+    enable_compile_cache()
     smoke = args.tasks is not None
     if smoke:
         fleets = (1,)

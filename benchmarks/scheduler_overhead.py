@@ -47,6 +47,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.endpoint import scaled_testbed, table1_testbed
 from repro.core.engine import OnlineEngine
 from repro.core.executor import attribute_window
@@ -62,11 +63,7 @@ from repro.core.scheduler import (
 )
 from repro.core.testbed import BASE_PROFILES, SEBS_FUNCTIONS, TestbedSim
 from repro.core.transfer import TransferModel
-
-try:  # the fused-scan engine needs jax; rows degrade gracefully without
-    from repro.kernels.placement import ops as placement_ops
-except Exception:  # pragma: no cover - jax-less environments
-    placement_ops = None
+from repro.kernels.placement import ops as placement_ops
 
 # (n_tasks, testbed replicas): the fleet grows with the workload, the way
 # a federation serving more users runs more sites
@@ -175,17 +172,14 @@ def run_scaling(sweep=SCALING_SWEEP, repeats=2, clone_max=1792):
         store = _seeded_store(eps)
         tm = TransferModel(eps)
         tasks = _tasks(n, src=eps[0].name)
-        engines = (["delta", "soa", "auto"]
-                   + (["jax"] if placement_ops is not None else [])
+        engines = (["delta", "soa", "auto", "jax"]
                    + (["clone"] if n <= clone_max else []))
         # jax is benchmarked warm: one untimed call absorbs the per-shape
         # XLA compile (reported separately) and also warms the cache the
         # auto rounds hit when they resolve to jax at large cells
-        compile_s = 0.0
-        if "jax" in engines:
-            c0 = placement_ops.COMPILE_STATS["seconds"]
-            mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
-            compile_s = placement_ops.COMPILE_STATS["seconds"] - c0
+        c0 = placement_ops.COMPILE_STATS["seconds"]
+        mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
+        compile_s = placement_ops.COMPILE_STATS["seconds"] - c0
         # the auto gate compares engines at the 5% level, tighter than
         # back-to-back timing noise on a shared box — so repeats are
         # interleaved round-robin in snake order (monotone load drift
@@ -209,10 +203,9 @@ def run_scaling(sweep=SCALING_SWEEP, repeats=2, clone_max=1792):
         objectives_bitwise = objectives_bitwise and o_bit
         a_eq, o_ok, _ = _check_pair(scheds["auto"], scheds["delta"])
         parity_ok = parity_ok and a_eq and o_ok
-        if "jax" in scheds:
-            a_eq, _, o_bit = _check_pair(scheds["jax"], scheds["soa"])
-            parity_ok = parity_ok and a_eq
-            jax_bitwise = jax_bitwise and a_eq and o_bit
+        a_eq, _, o_bit = _check_pair(scheds["jax"], scheds["soa"])
+        parity_ok = parity_ok and a_eq
+        jax_bitwise = jax_bitwise and a_eq and o_bit
         if "clone" in scheds:
             a_eq, o_ok, _ = _check_pair(scheds["delta"], scheds["clone"])
             parity_ok = parity_ok and a_eq and o_ok
@@ -224,9 +217,7 @@ def run_scaling(sweep=SCALING_SWEEP, repeats=2, clone_max=1792):
         pair = []
         for r, t_auto in enumerate(samples["auto"]):
             t_delta = samples["delta"][min(r, len(samples["delta"]) - 1)]
-            t_best = min(t_delta, samples["soa"][r])
-            if "jax" in samples:
-                t_best = min(t_best, samples["jax"][r])
+            t_best = min(t_delta, samples["soa"][r], samples["jax"][r])
             pair.append(t_auto / t_best)
         auto_ok = auto_ok and min(pair) <= 1.05
         for engine in engines:
@@ -395,6 +386,7 @@ def _parse(argv):
 
 def _run_all(args):
     """(harness_rows, ok): run every section, print, write the JSON."""
+    enable_compile_cache()
     if args.tasks is not None:
         sweep = ((args.tasks, 1),)
         t4_sizes = (args.tasks,)

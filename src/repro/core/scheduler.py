@@ -75,9 +75,9 @@ def reset_memo_stats() -> None:
 AUTO_SOA_MIN_ENDPOINTS = 16
 AUTO_SOA_MIN_CELLS = 256
 
-#: ``engine="jax"`` crossover (measured on the scaled SeBS testbed, warm
-#: timings with the one-off JIT compile accounted separately — see
-#: BENCH_scheduler.json): the fused lax.scan greedy beats soa once the
+#: ``engine="jax"`` crossover (measured with XLA:CPU on the scaled SeBS
+#: testbed, warm timings with the one-off JIT compile accounted
+#: separately — not yet measured on a TPU): the fused lax.scan greedy beats soa once the
 #: window is deep enough to amortize host array prep and device
 #: round-trips — measured from 8 endpoints at 8k-task windows (2^16
 #: score cells; jax 0.18s vs soa 0.30s there, and the margin only grows
@@ -85,21 +85,6 @@ AUTO_SOA_MIN_CELLS = 256
 #: switch (the vector passes don't pay for the scan's fixed overhead).
 AUTO_JAX_MIN_ENDPOINTS = 8
 AUTO_JAX_MIN_CELLS = 1 << 16
-
-_JAX_OK: bool | None = None
-
-
-def _jax_available() -> bool:
-    """Lazy probe: is the jax placement backend importable?  ``auto``
-    must never resolve to an engine that cannot run."""
-    global _JAX_OK
-    if _JAX_OK is None:
-        try:
-            import repro.kernels.placement.ops  # noqa: F401
-            _JAX_OK = True
-        except Exception:
-            _JAX_OK = False
-    return _JAX_OK
 
 
 def auto_engine(n_endpoints: int, n_tasks: int | None = None) -> str:
@@ -113,11 +98,9 @@ def auto_engine(n_endpoints: int, n_tasks: int | None = None) -> str:
     conservatively — delta is never worse than soa by much at small
     fleets, while soa's setup can triple a tiny window's latency.  Above
     the jax crossover (large fleet *and* a deep window to scan over) the
-    fused ``engine="jax"`` backend takes over — batch-size-aware only,
-    and only when jax is importable."""
+    fused ``engine="jax"`` backend takes over — batch-size-aware only."""
     if (n_tasks is not None and n_endpoints >= AUTO_JAX_MIN_ENDPOINTS
-            and n_endpoints * n_tasks >= AUTO_JAX_MIN_CELLS
-            and _jax_available()):
+            and n_endpoints * n_tasks >= AUTO_JAX_MIN_CELLS):
         return "jax"
     if n_endpoints >= AUTO_SOA_MIN_ENDPOINTS:
         return "soa"
@@ -988,18 +971,16 @@ def _mhra_jax(units, unit_indices, endpoints, table, transfer, alpha,
     and first-min argmins break ties like ``np.argmin``.  Windows the fast
     path can't express (clustered units, multi-input tasks — e.g. DAG
     join stages whose promoted children carry several parent transfers)
-    fall back to :func:`_mhra_soa`, which is assignment-identical by the
-    existing contract.  The live ``SoAState`` is read into device arrays
-    at the window boundary and only the winner's registers are written
-    back — no per-decision host/device chatter.
+    are handed to :func:`_mhra_soa`, which is assignment-identical by the
+    existing contract, and counted in ``ops.WINDOW_STATS["soa"]``.  The
+    live ``SoAState`` is read into device arrays at the window boundary
+    and only the winner's registers are written back — no per-decision
+    host/device chatter.  If the device path cannot import, this raises.
     """
+    from repro.kernels.placement import ops as pops
+
     if (not units) or any(len(u) != 1 or len(u[0].inputs) > 1 for u in units):
-        return _mhra_soa(units, unit_indices, endpoints, table, transfer,
-                         alpha, heuristics, sf1, sf2, state, carbon, sf3,
-                         lookahead, alive, warm, fairness)
-    try:
-        from repro.kernels.placement import ops as pops
-    except Exception:
+        pops.WINDOW_STATS["soa"] += 1
         return _mhra_soa(units, unit_indices, endpoints, table, transfer,
                          alpha, heuristics, sf1, sf2, state, carbon, sf3,
                          lookahead, alive, warm, fairness)

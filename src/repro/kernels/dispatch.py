@@ -5,6 +5,7 @@
 "xla"               — chunked pure-jnp path (CPU dry-run / fallback)
 
 Default: pallas on TPU, xla elsewhere; override with REPRO_KERNEL_BACKEND.
+The placement pass has its own selection (``placement_backend``).
 """
 from __future__ import annotations
 
@@ -37,22 +38,30 @@ _P_VALID = ("pallas", "pallas_interpret", "xla", "ref")
 def placement_backend() -> str:
     """Backend for the placement score+argmin pass.
 
-    Honors REPRO_PLACEMENT_BACKEND=pallas|xla|ref; "pallas" off-TPU is
-    coerced to interpret mode so the kernel path stays testable in CI.
-    Falls back to the generic kernel backend() default when unset.
+    ``xla`` (the fused jnp scan) on every platform unless
+    REPRO_PLACEMENT_BACKEND names ``pallas_interpret`` or ``ref``.  The
+    Pallas placement kernel's contract is float64, which Mosaic cannot
+    lower for a TPU, so ``pallas`` raises instead of compiling (or of
+    silently running in interpret mode off the TPU).
     """
     env = os.environ.get("REPRO_PLACEMENT_BACKEND")
-    if env:
-        assert env in _P_VALID, env
-        if env == "pallas" and jax.default_backend() != "tpu":
-            return "pallas_interpret"
-        return env
-    return backend()
+    if not env:
+        return "xla"
+    if env not in _P_VALID:
+        raise ValueError(f"REPRO_PLACEMENT_BACKEND={env!r}; expected one "
+                         f"of {_P_VALID}")
+    if env == "pallas":
+        raise ValueError(
+            "REPRO_PLACEMENT_BACKEND=pallas: the placement kernel's float64 "
+            "contract cannot lower for a TPU (Mosaic refuses the f64 SMEM "
+            "scalars: 'Only arrays with 32-bit element types can be "
+            "converted to scalars'; in float32 it refuses the (1, 1) "
+            "outputs: 'Cannot store scalars to VMEM').  Use 'xla' (the "
+            "default) on the chip, or 'pallas_interpret' to emulate the "
+            "kernel on the host."
+        )
+    return env
 
 
 def placement_use_pallas() -> bool:
-    return placement_backend() in ("pallas", "pallas_interpret")
-
-
-def placement_interpret() -> bool:
     return placement_backend() == "pallas_interpret"

@@ -22,7 +22,7 @@ carry registers and commits via a first-min argmin, reproducing
   program covers every register combination — no per-flag recompiles.
 
 Shapes are padded: endpoints and cores to power-of-two buckets (lanes to
-a 128 multiple under the Pallas backend), tasks and input signatures to
+a 128 multiple under the Pallas kernel), tasks and input signatures to
 power-of-two buckets, so a campaign compiles at most ``log2`` variants
 per axis.  ``x64`` is scoped to every placement entry point (the whole
 parity contract is float64) without flipping the process-global flag —
@@ -33,13 +33,10 @@ so no ``inf - inf`` NaN can poison a decision.
 """
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
-from jax.experimental import enable_x64
-
-import functools
-
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -55,6 +52,13 @@ from repro.kernels.placement import ref as _ref
 #: :func:`reset_compile_stats`.
 COMPILE_STATS = {"compiles": 0, "seconds": 0.0}
 
+#: ``engine="jax"`` window accounting: ``device`` counts windows placed by
+#: :func:`greedy_window`, ``soa`` counts windows the jax engine handed to
+#: the host SoA greedy because the fused scan cannot express them
+#: (clustered units, multi-input tasks).  Cumulative; reset with
+#: :func:`reset_window_stats`.
+WINDOW_STATS = {"device": 0, "soa": 0}
+
 _seen_signatures: set[tuple] = set()
 
 
@@ -62,6 +66,11 @@ def reset_compile_stats() -> None:
     COMPILE_STATS["compiles"] = 0
     COMPILE_STATS["seconds"] = 0.0
     _seen_signatures.clear()
+
+
+def reset_window_stats() -> None:
+    WINDOW_STATS["device"] = 0
+    WINDOW_STATS["soa"] = 0
 
 
 def bucket_pow2(n: int, minimum: int = 1) -> int:
@@ -86,8 +95,8 @@ def score_fleet(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
     """Standalone fused score+argmin over one candidate fleet.
 
     Dispatches on :func:`repro.kernels.dispatch.placement_backend`:
-    ``ref`` (NumPy oracle), ``xla`` (pure jnp), or ``pallas`` /
-    ``pallas_interpret`` (tiled kernel).  Returns ``(obj, argmin)`` with
+    ``ref`` (NumPy oracle), ``xla`` (pure jnp), or ``pallas_interpret``
+    (the tiled kernel, emulated).  Returns ``(obj, argmin)`` with
     ``obj`` over the true (unpadded) fleet.  The in-scan twin of this op
     is traced inside :func:`greedy_window`; this entry point exists for
     tests and for scoring outside a jit context.
@@ -96,7 +105,7 @@ def score_fleet(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
     if be != "ref":
         # the parity contract is float64: scope x64 to this call instead
         # of flipping the process-global flag (other kernels trace f32)
-        with enable_x64():
+        with jax.enable_x64(True):
             return _score_fleet_jax(
                 e_base, nl, g_base, lk, fw, wt, alive, c_cur,
                 idle_on_sum, a1, b1, g1, w_idle_on, be,
@@ -117,7 +126,7 @@ def score_fleet(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
 def _score_fleet_jax(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
                      idle_on_sum, a1, b1, g1, w_idle_on, be):
     n = len(e_base)
-    if be in ("pallas", "pallas_interpret"):
+    if be == "pallas_interpret":
         lanes = ((n + 127) // 128) * 128
         pad = lanes - n
 
@@ -131,7 +140,7 @@ def _score_fleet_jax(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
         alive_f = p(jnp.asarray(alive, dtype=jnp.float64))
         obj, _, idx = _kernel.score_fleet(
             scalars, p(e_base), p(nl), p(g_base), p(lk), p(fw), p(wt),
-            alive_f, interpret=(be == "pallas_interpret"),
+            alive_f, interpret=True,
         )
         return np.asarray(obj)[:n], int(idx)
     obj = _score_lanes(
@@ -160,14 +169,14 @@ def _score_lanes(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
     return jnp.where(alive, obj, jnp.inf)
 
 
-@functools.partial(jax.jit, static_argnames=("n_ep", "use_kernel",
-                                             "interpret"))
-def _greedy_scan(consts, init, xs, *, n_ep, use_kernel, interpret):
+@functools.partial(jax.jit, static_argnames=("n_ep", "use_kernel"))
+def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
     """vmapped-over-heuristics scan; see ``greedy_window`` for the layout.
 
     ``n_ep`` (the *true* fleet size) is static: the run-basis scalars are
     summed over exactly the first ``n_ep`` lanes with numpy's pairwise
-    association, unrolled at trace time.
+    association, unrolled at trace time.  ``use_kernel`` scores through
+    the Pallas kernel in interpret mode instead of the fused jnp pass.
     """
     sc = consts["scalars"]
     a1, b1, g1 = sc["a1"], sc["b1"], sc["g1"]
@@ -253,7 +262,7 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel, interpret):
             alive_f = alive_m.astype(jnp.float64)
             _, _, ei = _kernel.score_fleet(
                 scalars, e_base, nl_r, g_base_r, lk_r, fw_r, wt, alive_f,
-                interpret=interpret,
+                interpret=True,
             )
         else:
             obj = _score_lanes(e_base, nl_r, g_base_r, lk_r, fw_r, wt,
@@ -357,9 +366,8 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     and timed — as a compile).
     """
     use_kernel = dispatch.placement_use_pallas()
-    interpret = dispatch.placement_interpret()
     sig = (
-        n_ep, use_kernel, interpret,
+        n_ep, use_kernel,
         tuple(sorted((k, np.shape(v)) for k, v in xs.items())),
         tuple(sorted((k, np.shape(v)) for k, v in init.items())),
         tuple(sorted((k, np.shape(v)) for k, v in consts.items()
@@ -372,17 +380,17 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     # x64 is scoped to the placement scan (trace + execute) rather than
     # enabled process-wide: the parity contract is float64, but sibling
     # kernels in this package trace float32 and must stay untouched
-    with enable_x64():
+    with jax.enable_x64(True):
         jxs = jax.tree_util.tree_map(jnp.asarray, xs)
         jinit = jax.tree_util.tree_map(jnp.asarray, init)
         jconsts = jax.tree_util.tree_map(jnp.asarray, consts)
         carry, ys = _greedy_scan(jconsts, _as_tuple_carry(jinit), jxs,
-                                 n_ep=n_ep, use_kernel=use_kernel,
-                                 interpret=interpret)
+                                 n_ep=n_ep, use_kernel=use_kernel)
         carry = jax.block_until_ready(carry)
     if t0 is not None:
         COMPILE_STATS["compiles"] += 1
         COMPILE_STATS["seconds"] += time.perf_counter() - t0
+    WINDOW_STATS["device"] += 1
     names = ("mins", "slots", "first", "last", "dyn", "const", "const_g",
              "e_base", "nl_r", "g_base_r", "lk_r", "fw_r", "staged",
              "c_cur", "tj", "c_sum_b", "tj_b", "cg_sum_b")

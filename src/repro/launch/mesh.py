@@ -13,7 +13,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=_auto(axes))
     # dry-run: 512 host devices present; single-pod mesh uses the first 256
     import numpy as np
 
@@ -24,4 +24,11 @@ def make_host_mesh(model: int = 1):
     """Tiny mesh over the real local devices (tests/examples on CPU)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    axes = ("data", "model")
+    return jax.make_mesh((n // model, model), axes, axis_types=_auto(axes))
+
+
+def _auto(axes):
+    # make_mesh builds Explicit axes by default; ShardCtx places activations
+    # with with_sharding_constraint, which only refers to Auto axes
+    return (jax.sharding.AxisType.Auto,) * len(axes)

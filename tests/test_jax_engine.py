@@ -1,34 +1,33 @@
 """jax-engine parity suite: engine="jax" must reproduce engine="soa"
 assignments AND objectives bitwise — the fused scan replays the SoA
 float sequence double for double — batch and online, under every scoring
-register, falling back to soa on windows the fused path can't express
-(clustered units, multi-input tasks)."""
+register, handing windows the fused path can't express (clustered units,
+multi-input tasks) to soa, visibly (``ops.WINDOW_STATS``)."""
+import sys
+
 import numpy as np
 import pytest
 
-pops = pytest.importorskip(
-    "repro.kernels.placement.ops",
-    reason="jax placement backend unavailable (no jax in this environment)",
-)
-
-from repro.core import scheduler as sched  # noqa: E402
-from repro.core.carbon import CarbonWeights  # noqa: E402
-from repro.core.dag import LookaheadWeights  # noqa: E402
-from repro.core.endpoint import scaled_testbed, table1_testbed  # noqa: E402
-from repro.core.engine import OnlineEngine  # noqa: E402
-from repro.core.fairness import FairnessWeights  # noqa: E402
-from repro.core.faults import WarmWeights  # noqa: E402
-from repro.core.policy import get_policy  # noqa: E402
-from repro.core.predictor import TaskProfileStore  # noqa: E402
-from repro.core.scheduler import (  # noqa: E402
+import repro.kernels.placement
+from repro.core import scheduler as sched
+from repro.core.carbon import CarbonWeights
+from repro.core.dag import LookaheadWeights
+from repro.core.endpoint import scaled_testbed, table1_testbed
+from repro.core.engine import OnlineEngine
+from repro.core.fairness import FairnessWeights
+from repro.core.faults import WarmWeights
+from repro.core.policy import get_policy
+from repro.core.predictor import TaskProfileStore
+from repro.core.scheduler import (
     SoAState,
     TaskSpec,
     auto_engine,
     cluster_mhra,
     mhra,
 )
-from repro.core.testbed import BASE_PROFILES, SEBS_FUNCTIONS, TestbedSim  # noqa: E402
-from repro.core.transfer import TransferModel  # noqa: E402
+from repro.core.testbed import BASE_PROFILES, SEBS_FUNCTIONS, TestbedSim
+from repro.core.transfer import TransferModel
+from repro.kernels.placement import ops as pops
 
 
 def _setup(n_per=12, with_inputs=True, replicas=1):
@@ -117,7 +116,7 @@ def test_jax_matches_soa_all_registers():
 
 
 # ---------------------------------------------------------------------------
-# fallback paths (fused scan can't express the window -> soa, which is
+# hand-off paths (fused scan can't express the window -> soa, which is
 # parity-locked already)
 # ---------------------------------------------------------------------------
 
@@ -206,10 +205,52 @@ def test_auto_engine_jax_tier():
     assert auto_engine(10 ** 4) == "soa"
 
 
+def _hide_placement_ops(monkeypatch):
+    """Make ``repro.kernels.placement.ops`` un-importable."""
+    monkeypatch.setitem(sys.modules, "repro.kernels.placement.ops", None)
+    monkeypatch.delattr(repro.kernels.placement, "ops", raising=False)
+
+
 def test_auto_engine_jax_requires_importable_backend(monkeypatch):
-    monkeypatch.setattr(sched, "_JAX_OK", False)
+    """auto decides on fleet size and window depth only — it never probes
+    the import — so a window it routes to jax raises when the device path
+    cannot import, instead of silently running soa."""
+    _hide_placement_ops(monkeypatch)
     me, mc = sched.AUTO_JAX_MIN_ENDPOINTS, sched.AUTO_JAX_MIN_CELLS
-    assert auto_engine(me, mc // me) == "soa"
+    assert auto_engine(me, mc // me) == "jax"
+    tasks, eps, store, tm = _setup(n_per=3, with_inputs=False, replicas=2)
+    monkeypatch.setattr(sched, "AUTO_JAX_MIN_ENDPOINTS", len(eps))
+    monkeypatch.setattr(sched, "AUTO_JAX_MIN_CELLS", len(eps) * len(tasks))
+    with pytest.raises(ImportError):
+        mhra(tasks, eps, store, tm, alpha=0.5, engine="auto")
+
+
+def test_explicit_jax_raises_when_ops_cannot_import(monkeypatch):
+    _hide_placement_ops(monkeypatch)
+    tasks, eps, store, tm = _setup(n_per=2)
+    with pytest.raises(ImportError):
+        mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
+    eng = OnlineEngine(eps, policy="mhra", engine="jax", store=store,
+                       monitoring=False)
+    eng.submit_many(tasks)
+    with pytest.raises(ImportError):
+        eng.flush()
+
+
+def test_window_stats_count_device_and_soa_windows():
+    """A clustered window is handed to soa and counted as such; a plain
+    window is placed by the scan and counted as a device window."""
+    tasks, eps, store, tm = _setup(n_per=4)
+    pops.reset_window_stats()
+    cluster_mhra(tasks, eps, store, tm, alpha=0.5, max_cluster_size=16,
+                 engine="jax")
+    assert pops.WINDOW_STATS == {"device": 0, "soa": 1}
+    mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
+    assert pops.WINDOW_STATS == {"device": 1, "soa": 1}
+    mhra(tasks, eps, store, tm, alpha=0.5, engine="soa")
+    assert pops.WINDOW_STATS == {"device": 1, "soa": 1}
+    pops.reset_window_stats()
+    assert pops.WINDOW_STATS == {"device": 0, "soa": 0}
 
 
 def test_auto_batch_escalates_to_jax_and_matches_soa(monkeypatch):
@@ -234,26 +275,39 @@ def test_auto_batch_escalates_to_jax_and_matches_soa(monkeypatch):
 
 def test_placement_backend_env_override(monkeypatch):
     from repro.kernels import dispatch
+    monkeypatch.delenv("REPRO_PLACEMENT_BACKEND", raising=False)
+    # the fused jnp scan everywhere; it never inherits the generic
+    # kernel backend's "pallas"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    assert dispatch.placement_backend() == "xla"
+    assert not dispatch.placement_use_pallas()
+    assert pops.lane_bucket(32) == 32
     monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "ref")
     assert dispatch.placement_backend() == "ref"
     assert not dispatch.placement_use_pallas()
     monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "xla")
     assert dispatch.placement_backend() == "xla"
+    monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "pallas_interpret")
+    assert dispatch.placement_backend() == "pallas_interpret"
+    assert dispatch.placement_use_pallas()
+    assert pops.lane_bucket(32) == 128
+    # "pallas" is refused with the reason, never coerced to interpret mode
     monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "pallas")
-    import jax
-    if jax.default_backend() != "tpu":
-        # off-TPU the kernel path coerces to interpret mode so CI can
-        # still execute the Pallas body
-        assert dispatch.placement_backend() == "pallas_interpret"
-        assert dispatch.placement_interpret()
-    monkeypatch.delenv("REPRO_PLACEMENT_BACKEND")
-    assert dispatch.placement_backend() in ("pallas", "xla")
+    with pytest.raises(ValueError, match="float64"):
+        dispatch.placement_backend()
+    monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "mosaic")
+    with pytest.raises(ValueError, match="expected one of"):
+        dispatch.placement_backend()
+    monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "pallas")
+    tasks, eps, store, tm = _setup(n_per=1)
+    with pytest.raises(ValueError, match="pallas_interpret"):
+        mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
 
 
 def test_jax_matches_soa_under_pallas_interpret(monkeypatch):
     """The tiled Pallas score+argmin kernel (interpret mode on CPU) is
     parity-locked too, not just the fused-XLA path."""
-    monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "pallas")
+    monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", "pallas_interpret")
     tasks, eps, store, tm = _setup(n_per=4)
     a = mhra(tasks, eps, store, tm, alpha=0.3, engine="soa")
     b = mhra(tasks, eps, store, tm, alpha=0.3, engine="jax")

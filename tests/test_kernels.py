@@ -248,14 +248,15 @@ def test_placement_score_backends_bitwise(seed, n, monkeypatch):
     from repro.kernels.placement import ops as pops
     kw = _placement_case(seed, n)
     outs = {}
-    for be in ("ref", "xla", "pallas"):
+    for be in ("ref", "xla", "pallas_interpret"):
         monkeypatch.setenv("REPRO_PLACEMENT_BACKEND", be)
         obj, idx = pops.score_fleet(**kw)
         outs[be] = (np.asarray(obj), int(idx))
     np.testing.assert_array_equal(outs["ref"][0], outs["xla"][0])
     assert outs["ref"][1] == outs["xla"][1]
-    np.testing.assert_allclose(outs["ref"][0], outs["pallas"][0], rtol=5e-15)
-    assert outs["ref"][1] == outs["pallas"][1]
+    np.testing.assert_allclose(outs["ref"][0], outs["pallas_interpret"][0],
+                               rtol=5e-15)
+    assert outs["ref"][1] == outs["pallas_interpret"][1]
     # first-min tie-breaking matches np.argmin on the masked objective
     masked = np.where(kw["alive"], outs["ref"][0], np.inf)
     assert outs["ref"][1] == int(np.argmin(masked))
@@ -272,7 +273,7 @@ def test_placement_score_first_min_ties():
     kw["alive"] = np.ones(n, dtype=bool)
     import os
     prev = os.environ.get("REPRO_PLACEMENT_BACKEND")
-    for be in ("ref", "xla", "pallas"):
+    for be in ("ref", "xla", "pallas_interpret"):
         os.environ["REPRO_PLACEMENT_BACKEND"] = be
         try:
             _, idx = pops.score_fleet(**kw)
