@@ -43,6 +43,7 @@ from repro.core.region import (
 from repro.core.scheduler import (
     Schedule, SchedulerState, SoAState, TaskSpec, auto_engine,
 )
+from repro.core.spans import span, window as window_span
 from repro.core.testbed import SimResult, TestbedSim
 from repro.core.transfer import TransferModel, TransferRequest
 
@@ -457,6 +458,9 @@ class OnlineEngine:
         self._spec_wasted_j = 0.0
         self.clock = 0.0
         self._first_pending_at: float | None = None
+        # what closes the next window, for its span: "full" from submit,
+        # "timer" from tick, "call" otherwise (flush takes no arguments)
+        self._flush_trigger = "call"
         if backend is not None:
             backend.begin_stream()
 
@@ -478,6 +482,7 @@ class OnlineEngine:
             self._first_pending_at = when
         self.pending.append(task)
         if len(self.pending) >= self.max_batch:
+            self._flush_trigger = "full"
             return self.flush()
         return None
 
@@ -542,6 +547,7 @@ class OnlineEngine:
             and self._first_pending_at is not None
             and now - self._first_pending_at >= self.window_s
         ):
+            self._flush_trigger = "timer"
             return self.flush()
         return None
 
@@ -750,75 +756,91 @@ class OnlineEngine:
     # ------------------------------------------------------------------
     def flush(self) -> WindowResult | None:
         """Place and dispatch all pending tasks as one window."""
+        trigger, self._flush_trigger = self._flush_trigger, "call"
         if not self.pending:
             return None
-        tasks, self.pending = self.pending, []
         submitted_at = (
             self.clock if self._first_pending_at is None
             else self._first_pending_at
         )
-        self._first_pending_at = None
-        if self.carbon is not None and self.defer_horizon_s > 0.0:
-            tasks = self._split_deferrable(tasks, submitted_at)
-            if not tasks:
-                return None     # whole window shifted to a cleaner grid
-        if self.fairness is not None:
-            self.fairness.advance(submitted_at)
-            if self.admission is not None:
-                tasks = self._admit(tasks, submitted_at)
-                if not tasks:
-                    return None     # whole window shed/deferred over budget
+        with window_span(self._n_windows, tasks=len(self.pending),
+                         trigger=trigger,
+                         wait_ms=(self.clock - submitted_at) * 1e3):
+            return self._flush(submitted_at)
 
-        if self.state is None:
-            # engine="auto": first window — resolve the crossover on the
-            # actual fleet and window size, then keep that layout for life
-            self.engine = auto_engine(len(self.endpoints), len(tasks))
-            state_cls = (SoAState if self.engine in ("soa", "jax")
-                         else SchedulerState)
-            self.state = state_cls(self.endpoints, self.transfer)
-        alive = warm = None
-        if self.fault_aware:
-            if self.faults is not None:
-                alive_l = [self.faults.is_up(e.name, submitted_at)
-                           for e in self.endpoints]
-                if not any(alive_l):
-                    # whole fleet dark: open the window at the earliest
-                    # recovery instead of placing onto dead endpoints
-                    t_up = min(self.faults.next_up(e.name, submitted_at)
-                               for e in self.endpoints)
-                    if t_up == float("inf"):
-                        raise RuntimeError(
-                            "every endpoint is down and none recovers: "
-                            "cannot place this window"
-                        )
-                    submitted_at = t_up
-                    self.clock = max(self.clock, t_up)
+    def _flush(self, submitted_at: float) -> WindowResult | None:
+        tasks, self.pending = self.pending, []
+        self._first_pending_at = None
+        with span("prepare"):
+            if self.carbon is not None and self.defer_horizon_s > 0.0:
+                tasks = self._split_deferrable(tasks, submitted_at)
+                if not tasks:
+                    return None     # whole window shifted to a cleaner grid
+            if self.fairness is not None:
+                self.fairness.advance(submitted_at)
+                if self.admission is not None:
+                    tasks = self._admit(tasks, submitted_at)
+                    if not tasks:
+                        return None     # whole window shed/deferred over budget
+
+            if self.state is None:
+                # engine="auto": first window — resolve the crossover on the
+                # actual fleet and window size, then keep that layout for life
+                self.engine = auto_engine(len(self.endpoints), len(tasks))
+                state_cls = (SoAState if self.engine in ("soa", "jax")
+                             else SchedulerState)
+                self.state = state_cls(self.endpoints, self.transfer)
+            alive = warm = None
+            if self.fault_aware:
+                if self.faults is not None:
                     alive_l = [self.faults.is_up(e.name, submitted_at)
                                for e in self.endpoints]
-                if not all(alive_l):
-                    alive = tuple(alive_l)
-            # snapshot idle gaps before advance_to erases them
-            warm = WarmWeights.from_state(
-                self.endpoints, self.state, submitted_at, self.faults
+                    if not any(alive_l):
+                        # whole fleet dark: open the window at the earliest
+                        # recovery instead of placing onto dead endpoints
+                        t_up = min(self.faults.next_up(e.name, submitted_at)
+                                   for e in self.endpoints)
+                        if t_up == float("inf"):
+                            raise RuntimeError(
+                                "every endpoint is down and none recovers: "
+                                "cannot place this window"
+                            )
+                        submitted_at = t_up
+                        self.clock = max(self.clock, t_up)
+                        alive_l = [self.faults.is_up(e.name, submitted_at)
+                                   for e in self.endpoints]
+                    if not all(alive_l):
+                        alive = tuple(alive_l)
+                # snapshot idle gaps before advance_to erases them
+                warm = WarmWeights.from_state(
+                    self.endpoints, self.state, submitted_at, self.faults
+                )
+            fair_w = (
+                FairnessWeights.from_ledger(self.fairness, tasks)
+                if self.fairness is not None else None
             )
-        fair_w = (
-            FairnessWeights.from_ledger(self.fairness, tasks)
-            if self.fairness is not None else None
-        )
-        ctx = PolicyContext(self.endpoints, self.store, self.transfer,
-                            self.alpha, carbon=self.carbon, now=submitted_at,
-                            dag=self.dag, alive=alive, warm=warm,
-                            fairness=fair_w)
-        # placement previews must not start tasks before this window opened
-        self.state.advance_to(submitted_at)
-        t0 = time.perf_counter()
-        if self.router is None:
-            schedule = self.policy.place(tasks, ctx, state=self.state)
-        else:
-            tasks, schedule = self._place_regions(
-                tasks, ctx, submitted_at, alive
-            )
-        sched_s = time.perf_counter() - t0
+            ctx = PolicyContext(self.endpoints, self.store, self.transfer,
+                                self.alpha, carbon=self.carbon, now=submitted_at,
+                                dag=self.dag, alive=alive, warm=warm,
+                                fairness=fair_w)
+            # placement previews must not start tasks before this window opened
+            self.state.advance_to(submitted_at)
+        with span("place"):
+            t0 = time.perf_counter()
+            if self.router is None:
+                schedule = self.policy.place(tasks, ctx, state=self.state)
+            else:
+                tasks, schedule = self._place_regions(
+                    tasks, ctx, submitted_at, alive
+                )
+            sched_s = time.perf_counter() - t0
+        with span("complete"):
+            return self._complete(tasks, schedule, submitted_at, sched_s)
+
+    def _complete(self, tasks: list[TaskSpec], schedule: Schedule,
+                  submitted_at: float, sched_s: float) -> WindowResult:
+        """Dispatch a placed window, record its completions and open the
+        next one's ready tasks."""
         assignments = {t.id: schedule.assignments[t.id] for t in tasks}
 
         sim = None

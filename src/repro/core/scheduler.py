@@ -49,6 +49,7 @@ from repro.core.endpoint import EndpointSpec
 from repro.core.fairness import FairnessWeights
 from repro.core.faults import WarmWeights
 from repro.core.predictor import Prediction, TaskProfileStore
+from repro.core.spans import span
 from repro.core.transfer import E_INC_J_PER_BYTE, TransferModel
 
 #: Run-memoization counters for the SoA greedy (``_greedy_soa``): a "hit"
@@ -883,22 +884,28 @@ def mhra(
         raise ValueError(f"unknown engine {engine!r}")
 
     tasks = list(tasks)
-    table = PredictionTable(tasks, endpoints, store)
-    if clusters is None:
-        units = [[t] for t in tasks]
-    else:
-        units = [[tasks[i] for i in c] for c in clusters]
-    sf1, sf2, sf3 = _normalizers_fast(tasks, endpoints, table, transfer, carbon)
+    with span("predict"):
+        table = PredictionTable(tasks, endpoints, store)
+        if clusters is None:
+            units = [[t] for t in tasks]
+        else:
+            units = [[tasks[i] for i in c] for c in clusters]
+        unit_indices = [[table.index[t.id] for t in u] for u in units]
+    with span("normalizers"):
+        sf1, sf2, sf3 = _normalizers_fast(tasks, endpoints, table, transfer,
+                                          carbon)
 
-    unit_indices = [[table.index[t.id] for t in u] for u in units]
-    if engine == "jax":
-        return _mhra_jax(units, unit_indices, endpoints, table, transfer,
-                         alpha, heuristics, sf1, sf2, state, carbon, sf3,
-                         lookahead, alive, warm, fairness)
-    if engine == "soa":
-        return _mhra_soa(units, unit_indices, endpoints, table, transfer,
-                         alpha, heuristics, sf1, sf2, state, carbon, sf3,
-                         lookahead, alive, warm, fairness)
+    if engine in ("jax", "soa"):
+        search = _mhra_jax if engine == "jax" else _mhra_soa
+        sched = search(units, unit_indices, endpoints, table, transfer,
+                       alpha, heuristics, sf1, sf2, state, carbon, sf3,
+                       lookahead, alive, warm, fairness)
+        # freeing the window's table and unit lists is a phase of its own at
+        # deep windows (~10 ms for the 2 x 32 x 8192 floats of the table's
+        # rows on a host CPU), so it happens inside a span
+        with span("release"):
+            del table, units, unit_indices
+        return sched
     soa_live: SoAState | None = None
     if isinstance(state, SoAState):
         # delta engine over a SoA-backed live state: run on a heap view,
@@ -932,29 +939,30 @@ def _mhra_soa(units, unit_indices, endpoints, table, transfer, alpha,
               lookahead=None, alive=None, warm=None, fairness=None):
     """SoA-engine heuristic search: run :func:`_greedy_soa` per ordering
     heuristic, commit the winner into ``state`` (heap- or SoA-backed)."""
-    heap_state: SchedulerState | None = None
-    if isinstance(state, SchedulerState):
-        heap_state, state = state, SoAState.from_heap(state)
-    best: Schedule | None = None
-    best_state: SoAState | None = None
-    for h in heuristics:
-        order = _sort_order(h, table, unit_indices)
-        ordered = [units[i] for i in order]
-        ordered_idx = [unit_indices[i] for i in order]
-        sched, end_state = _greedy_soa(
-            ordered, ordered_idx, endpoints, table, transfer, alpha,
-            sf1, sf2, h, state, carbon, sf3, lookahead, alive, warm,
-            fairness,
-        )
-        if best is None or sched.objective < best.objective:
-            best, best_state = sched, end_state
-    if heap_state is not None:
-        best_state.write_back(heap_state)
-        best.timeline = dict(best.timeline)
-    elif state is not None:
-        state.replace_with(best_state)
-        best.timeline = dict(best.timeline)
-    return best
+    with span("soa"):
+        heap_state: SchedulerState | None = None
+        if isinstance(state, SchedulerState):
+            heap_state, state = state, SoAState.from_heap(state)
+        best: Schedule | None = None
+        best_state: SoAState | None = None
+        for h in heuristics:
+            order = _sort_order(h, table, unit_indices)
+            ordered = [units[i] for i in order]
+            ordered_idx = [unit_indices[i] for i in order]
+            sched, end_state = _greedy_soa(
+                ordered, ordered_idx, endpoints, table, transfer, alpha,
+                sf1, sf2, h, state, carbon, sf3, lookahead, alive, warm,
+                fairness,
+            )
+            if best is None or sched.objective < best.objective:
+                best, best_state = sched, end_state
+        if heap_state is not None:
+            best_state.write_back(heap_state)
+            best.timeline = dict(best.timeline)
+        elif state is not None:
+            state.replace_with(best_state)
+            best.timeline = dict(best.timeline)
+        return best
 
 
 def _mhra_jax(units, unit_indices, endpoints, table, transfer, alpha,
@@ -985,329 +993,333 @@ def _mhra_jax(units, unit_indices, endpoints, table, transfer, alpha,
                          alpha, heuristics, sf1, sf2, state, carbon, sf3,
                          lookahead, alive, warm, fairness)
 
-    heap_state: SchedulerState | None = None
-    if isinstance(state, SchedulerState):
-        heap_state, state = state, SoAState.from_heap(state)
-    base = state if state is not None else SoAState(endpoints, transfer)
-    n_ep = len(endpoints)
-    names = base.names
+    with span("pack"):
+        heap_state: SchedulerState | None = None
+        if isinstance(state, SchedulerState):
+            heap_state, state = state, SoAState.from_heap(state)
+        base = state if state is not None else SoAState(endpoints, transfer)
+        n_ep = len(endpoints)
+        names = base.names
 
-    # per-endpoint constants — same host numpy expressions as _greedy_soa,
-    # so every scalar entering the scan is the same double
-    idle = np.array([ep.idle_power_w for ep in endpoints])
-    bt_mask = np.array([ep.has_batch_scheduler for ep in endpoints])
-    su = np.array([ep.startup_energy_j for ep in endpoints])
-    qd_vec = np.where(bt_mask, [ep.queue_delay_s for ep in endpoints], 0.0)
-    idle_bt = np.where(bt_mask, idle, 0.0)
-    su_bt = np.where(bt_mask, su, 0.0)
-    idle_on_sum = float(idle[~bt_mask].sum())
-    c_cur0 = float(max(base.last.max(initial=0.0), 0.0))
-    used = base.first < np.inf
-    span0 = np.where(used, base.last - base.first, 0.0)
-    const0 = np.where(bt_mask & used, idle * span0 + su, 0.0) + base.dyn
-    a1 = alpha / sf1
-    b1 = (1.0 - alpha) / sf2
-    if carbon is not None:
-        rates_v = np.asarray(carbon.rates, dtype=float)
-        g1 = carbon.gamma / sf3
-        w_idle_on = float((rates_v * idle)[~bt_mask].sum())
-    else:
-        rates_v = np.zeros(n_ep)
-        g1 = 0.0
-        w_idle_on = 0.0
-    const_g0 = rates_v * const0
-    if lookahead is not None:
-        lk_tail, lk_out = lookahead.tail_w, lookahead.out_j
-        lk_ht = lookahead.hops_task
-        hm_vec = np.asarray(lookahead.hops_mean, dtype=float)
-        lam = lookahead.lam
-    else:
-        lk_tail = lk_out = lk_ht = None
-        hm_vec = np.zeros(n_ep)
-        lam = 0.0
-    lam_b1 = lam * b1   # lk_c1 = (lam*b1)*u_tw, soa's left-assoc grouping
-    lam_a1 = lam * a1
-    fdebt = fairness.debt if fairness is not None else None
-    f_mu = fairness.mu if fairness is not None else 0.0
-    f_beta = 1.0 - alpha
-    wt_v = (np.asarray(_warm_terms(warm, alpha, sf1, sf2))
-            if warm is not None else np.zeros(n_ep))
-    alive_v = (np.ones(n_ep, dtype=bool) if alive is None
-               else np.asarray(alive, dtype=bool))
+        # per-endpoint constants — same host numpy expressions as _greedy_soa,
+        # so every scalar entering the scan is the same double
+        idle = np.array([ep.idle_power_w for ep in endpoints])
+        bt_mask = np.array([ep.has_batch_scheduler for ep in endpoints])
+        su = np.array([ep.startup_energy_j for ep in endpoints])
+        qd_vec = np.where(bt_mask, [ep.queue_delay_s for ep in endpoints], 0.0)
+        idle_bt = np.where(bt_mask, idle, 0.0)
+        su_bt = np.where(bt_mask, su, 0.0)
+        idle_on_sum = float(idle[~bt_mask].sum())
+        c_cur0 = float(max(base.last.max(initial=0.0), 0.0))
+        used = base.first < np.inf
+        span0 = np.where(used, base.last - base.first, 0.0)
+        const0 = np.where(bt_mask & used, idle * span0 + su, 0.0) + base.dyn
+        a1 = alpha / sf1
+        b1 = (1.0 - alpha) / sf2
+        if carbon is not None:
+            rates_v = np.asarray(carbon.rates, dtype=float)
+            g1 = carbon.gamma / sf3
+            w_idle_on = float((rates_v * idle)[~bt_mask].sum())
+        else:
+            rates_v = np.zeros(n_ep)
+            g1 = 0.0
+            w_idle_on = 0.0
+        const_g0 = rates_v * const0
+        if lookahead is not None:
+            lk_tail, lk_out = lookahead.tail_w, lookahead.out_j
+            lk_ht = lookahead.hops_task
+            hm_vec = np.asarray(lookahead.hops_mean, dtype=float)
+            lam = lookahead.lam
+        else:
+            lk_tail = lk_out = lk_ht = None
+            hm_vec = np.zeros(n_ep)
+            lam = 0.0
+        lam_b1 = lam * b1   # lk_c1 = (lam*b1)*u_tw, soa's left-assoc grouping
+        lam_a1 = lam * a1
+        fdebt = fairness.debt if fairness is not None else None
+        f_mu = fairness.mu if fairness is not None else 0.0
+        f_beta = 1.0 - alpha
+        wt_v = (np.asarray(_warm_terms(warm, alpha, sf1, sf2))
+                if warm is not None else np.zeros(n_ep))
+        alive_v = (np.ones(n_ep, dtype=bool) if alive is None
+                   else np.asarray(alive, dtype=bool))
 
-    # padded shapes: endpoint lanes / cores / tasks / input signatures
-    E = pops.lane_bucket(n_ep)
-    C = pops.bucket_pow2(max(ep.cores for ep in endpoints))
-    n_units = len(units)
-    T = pops.bucket_pow2(n_units)
-    H = len(heuristics)
+        # padded shapes: endpoint lanes / cores / tasks / input signatures
+        E = pops.lane_bucket(n_ep)
+        C = pops.bucket_pow2(max(ep.cores for ep in endpoints))
+        n_units = len(units)
+        T = pops.bucket_pow2(n_units)
+        H = len(heuristics)
 
-    def padv(v, fill=0.0):
-        out = np.full(E, fill, dtype=float)
-        out[:n_ep] = v
-        return out
+        def padv(v, fill=0.0):
+            out = np.full(E, fill, dtype=float)
+            out[:n_ep] = v
+            return out
 
-    # per-input-signature transfer table (slot 0 = the no-input dummy row:
-    # zero adds, zero ready, staged everywhere — bitwise-inert)
-    sig_index: dict[tuple, int] = {}
-    add_rows = [np.zeros(E)]
-    ready_list = [0.0]
-    shared_list = [False]
-    staged_rows = [np.ones(E, dtype=bool)]
-    keys_list: list[list] = [[None] * n_ep]
-    for u in units:
-        t0 = u[0]
-        if not t0.inputs:
-            continue
-        inp = t0.inputs[0]
-        if inp in sig_index:
-            continue
-        src, n_files, nbytes, shared = inp
-        ks = f"{src}:{n_files}:{nbytes}"
-        keys = [None if n == src else (n, ks) for n in names]
-        add = np.array([
-            0.0 if k is None
-            else transfer.hops(src, n) * nbytes * E_INC_J_PER_BYTE
-            for n, k in zip(names, keys)
-        ])
-        staged = np.array([
-            k is None or (shared and k in base.cached) for k in keys
-        ])
-        sig_index[inp] = len(add_rows)
-        add_rows.append(padv(add))
-        ready_list.append(transfer.predict_seconds(n_files, nbytes))
-        shared_list.append(bool(shared))
-        staged_rows.append(np.concatenate(
-            [staged, np.ones(E - n_ep, dtype=bool)]))
-        keys_list.append(keys)
-    n_sigs = len(add_rows)
-    S = pops.bucket_pow2(n_sigs)
-    staged0 = np.ones((S, E), dtype=bool)
-    staged0[:n_sigs] = np.stack(staged_rows)
+        # per-input-signature transfer table (slot 0 = the no-input dummy row:
+        # zero adds, zero ready, staged everywhere — bitwise-inert)
+        sig_index: dict[tuple, int] = {}
+        add_rows = [np.zeros(E)]
+        ready_list = [0.0]
+        shared_list = [False]
+        staged_rows = [np.ones(E, dtype=bool)]
+        keys_list: list[list] = [[None] * n_ep]
+        for u in units:
+            t0 = u[0]
+            if not t0.inputs:
+                continue
+            inp = t0.inputs[0]
+            if inp in sig_index:
+                continue
+            src, n_files, nbytes, shared = inp
+            ks = f"{src}:{n_files}:{nbytes}"
+            keys = [None if n == src else (n, ks) for n in names]
+            add = np.array([
+                0.0 if k is None
+                else transfer.hops(src, n) * nbytes * E_INC_J_PER_BYTE
+                for n, k in zip(names, keys)
+            ])
+            staged = np.array([
+                k is None or (shared and k in base.cached) for k in keys
+            ])
+            sig_index[inp] = len(add_rows)
+            add_rows.append(padv(add))
+            ready_list.append(transfer.predict_seconds(n_files, nbytes))
+            shared_list.append(bool(shared))
+            staged_rows.append(np.concatenate(
+                [staged, np.ones(E - n_ep, dtype=bool)]))
+            keys_list.append(keys)
+        n_sigs = len(add_rows)
+        S = pops.bucket_pow2(n_sigs)
+        staged0 = np.ones((S, E), dtype=bool)
+        staged0[:n_sigs] = np.stack(staged_rows)
 
-    # carry seeds from the live state (pad lanes: fresh-endpoint registers
-    # with zero slots — finite scores, masked dead before the argmin)
-    slots0 = np.full((E, C), np.inf)
-    slots0[n_ep:] = 0.0
-    for ei in range(n_ep):
-        sv = base.slot_view(ei)
-        slots0[ei, :len(sv)] = sv
-    mins0 = slots0.min(axis=1)
-    first0 = padv(base.first, fill=np.inf)
-    last0 = padv(base.last)
-    dyn0 = padv(base.dyn)
+        # carry seeds from the live state (pad lanes: fresh-endpoint registers
+        # with zero slots — finite scores, masked dead before the argmin)
+        slots0 = np.full((E, C), np.inf)
+        slots0[n_ep:] = 0.0
+        for ei in range(n_ep):
+            sv = base.slot_view(ei)
+            slots0[ei, :len(sv)] = sv
+        mins0 = slots0.min(axis=1)
+        first0 = padv(base.first, fill=np.inf)
+        last0 = padv(base.last)
+        dyn0 = padv(base.dyn)
 
-    hm_p = padv(hm_vec)
-    rtT, enT = table.transposed()
-    en_mean, rt_mean = table.en_mean, table.rt_mean
+        hm_p = padv(hm_vec)
+        rtT, enT = table.transposed()
+        en_mean, rt_mean = table.en_mean, table.rt_mean
 
-    def tile(a):
-        return np.broadcast_to(a, (H,) + a.shape).copy()
+        def tile(a):
+            return np.broadcast_to(a, (H,) + a.shape).copy()
 
-    xs = {
-        "ti": np.zeros((H, T), dtype=np.int32),
-        "hv_id": np.zeros((H, T), dtype=np.int32),
-        "sig": np.zeros((H, T), dtype=np.int32),
-        "ready_s": np.zeros((H, T)),
-        "shared_s": np.zeros((H, T), dtype=bool),
-        "nb": np.zeros((H, T)),
-        "new_run": np.zeros((H, T), dtype=bool),
-        "u_tw": np.zeros((H, T)),
-        "u_oj": np.zeros((H, T)),
-        "u_fd": np.zeros((H, T)),
-        "valid": np.zeros((H, T), dtype=bool),
-    }
-    # one pass over the units computes every order-independent per-task
-    # quantity; each heuristic then just permutes the shared arrays with
-    # fancy indexing (the ordering is the only thing heuristics change)
-    ti_all = np.fromiter((ui[0] for ui in unit_indices), dtype=np.intp,
-                         count=n_units)
-    nb_all = np.empty(n_units)
-    sig_all = np.zeros(n_units, dtype=np.int32)
-    u_tw_all = np.zeros(n_units)
-    u_oj_all = np.zeros(n_units)
-    u_fd_all = np.zeros(n_units)
-    gid_all = np.empty(n_units, dtype=np.int64)
-    key_ids: dict = {}
-    # hop-vector table: row 0 is the fleet mean; producer-aware tasks get
-    # their own (deduplicated) rows, indexed per task by ``hv_id``
-    hv_rows = [hm_p]
-    hv_ids: dict = {}
-    hv_id_all = np.zeros(n_units, dtype=np.int32)
-    tasks0 = [u[0] for u in units]
-    if lk_tail is None and fdebt is None:
-        # common case (no lookahead, no fairness): tight listcomp path —
-        # the same (fn, inputs, not_before) run keys, far fewer dispatches
-        key_list = [(t.fn, t.inputs, t.not_before) for t in tasks0]
-        nb_all[:] = [k[2] for k in key_list]
-        kid = key_ids.setdefault
-        gid_all[:] = [kid(k, len(key_ids)) for k in key_list]
-        if sig_index:
-            sidx = sig_index.get
-            sig_all[:] = [sidx(t.inputs[0], 0) if t.inputs else 0
-                          for t in tasks0]
-    else:
-        for i, t0 in enumerate(tasks0):
-            nb0 = t0.not_before
-            nb_all[i] = nb0
-            if lk_tail is not None:
-                u_tw = lk_tail.get(t0.id, 0.0)
-                u_oj = lk_out.get(t0.id, 0.0)
-                u_tw_all[i] = u_tw
-                u_oj_all[i] = u_oj
-                key = (t0.fn, t0.inputs, nb0, u_tw, u_oj)
-                if lk_ht is not None:
-                    # same run-key split as the SoA engine: tasks with
-                    # different consumer-hop vectors never share a run
-                    hv_t = lk_ht.get(t0.id)
-                    key = key + (hv_t,)
-                    if hv_t is not None:
-                        hid = hv_ids.get(hv_t)
-                        if hid is None:
-                            hid = hv_ids[hv_t] = len(hv_rows)
-                            hv_rows.append(padv(np.asarray(hv_t)))
-                        hv_id_all[i] = hid
-            else:
-                key = (t0.fn, t0.inputs, nb0)
-            if fdebt is not None:
-                u_fd = fdebt.get(t0.user, 0.0)
-                u_fd_all[i] = u_fd
-                key = key + (u_fd,)
-            if t0.inputs:
-                sig_all[i] = sig_index[t0.inputs[0]]
-            gid_all[i] = key_ids.setdefault(key, len(key_ids))
-    ready_arr = np.asarray(ready_list)
-    shared_arr = np.asarray(shared_list, dtype=bool)
+        xs = {
+            "ti": np.zeros((H, T), dtype=np.int32),
+            "hv_id": np.zeros((H, T), dtype=np.int32),
+            "sig": np.zeros((H, T), dtype=np.int32),
+            "ready_s": np.zeros((H, T)),
+            "shared_s": np.zeros((H, T), dtype=bool),
+            "nb": np.zeros((H, T)),
+            "new_run": np.zeros((H, T), dtype=bool),
+            "u_tw": np.zeros((H, T)),
+            "u_oj": np.zeros((H, T)),
+            "u_fd": np.zeros((H, T)),
+            "valid": np.zeros((H, T), dtype=bool),
+        }
+        # one pass over the units computes every order-independent per-task
+        # quantity; each heuristic then just permutes the shared arrays with
+        # fancy indexing (the ordering is the only thing heuristics change)
+        ti_all = np.fromiter((ui[0] for ui in unit_indices), dtype=np.intp,
+                             count=n_units)
+        nb_all = np.empty(n_units)
+        sig_all = np.zeros(n_units, dtype=np.int32)
+        u_tw_all = np.zeros(n_units)
+        u_oj_all = np.zeros(n_units)
+        u_fd_all = np.zeros(n_units)
+        gid_all = np.empty(n_units, dtype=np.int64)
+        key_ids: dict = {}
+        # hop-vector table: row 0 is the fleet mean; producer-aware tasks get
+        # their own (deduplicated) rows, indexed per task by ``hv_id``
+        hv_rows = [hm_p]
+        hv_ids: dict = {}
+        hv_id_all = np.zeros(n_units, dtype=np.int32)
+        tasks0 = [u[0] for u in units]
+        if lk_tail is None and fdebt is None:
+            # common case (no lookahead, no fairness): tight listcomp path —
+            # the same (fn, inputs, not_before) run keys, far fewer dispatches
+            key_list = [(t.fn, t.inputs, t.not_before) for t in tasks0]
+            nb_all[:] = [k[2] for k in key_list]
+            kid = key_ids.setdefault
+            gid_all[:] = [kid(k, len(key_ids)) for k in key_list]
+            if sig_index:
+                sidx = sig_index.get
+                sig_all[:] = [sidx(t.inputs[0], 0) if t.inputs else 0
+                              for t in tasks0]
+        else:
+            for i, t0 in enumerate(tasks0):
+                nb0 = t0.not_before
+                nb_all[i] = nb0
+                if lk_tail is not None:
+                    u_tw = lk_tail.get(t0.id, 0.0)
+                    u_oj = lk_out.get(t0.id, 0.0)
+                    u_tw_all[i] = u_tw
+                    u_oj_all[i] = u_oj
+                    key = (t0.fn, t0.inputs, nb0, u_tw, u_oj)
+                    if lk_ht is not None:
+                        # same run-key split as the SoA engine: tasks with
+                        # different consumer-hop vectors never share a run
+                        hv_t = lk_ht.get(t0.id)
+                        key = key + (hv_t,)
+                        if hv_t is not None:
+                            hid = hv_ids.get(hv_t)
+                            if hid is None:
+                                hid = hv_ids[hv_t] = len(hv_rows)
+                                hv_rows.append(padv(np.asarray(hv_t)))
+                            hv_id_all[i] = hid
+                else:
+                    key = (t0.fn, t0.inputs, nb0)
+                if fdebt is not None:
+                    u_fd = fdebt.get(t0.user, 0.0)
+                    u_fd_all[i] = u_fd
+                    key = key + (u_fd,)
+                if t0.inputs:
+                    sig_all[i] = sig_index[t0.inputs[0]]
+                gid_all[i] = key_ids.setdefault(key, len(key_ids))
+        ready_arr = np.asarray(ready_list)
+        shared_arr = np.asarray(shared_list, dtype=bool)
 
-    orders: list[np.ndarray] = []
-    memo_misses = 0
-    for hi, h in enumerate(heuristics):
-        order = np.asarray(_sort_order(h, table, unit_indices),
-                           dtype=np.intp)
-        orders.append(order)
-        xs["ti"][hi, :n_units] = ti_all[order]
-        xs["hv_id"][hi, :n_units] = hv_id_all[order]
-        xs["valid"][hi, :n_units] = True
-        g = gid_all[order]
-        nr = xs["new_run"][hi, :n_units]
-        nr[0] = True
-        np.not_equal(g[1:], g[:-1], out=nr[1:])
-        memo_misses += int(nr.sum())
-        s = sig_all[order]
-        xs["sig"][hi, :n_units] = s
-        xs["ready_s"][hi, :n_units] = ready_arr[s]
-        xs["shared_s"][hi, :n_units] = shared_arr[s]
-        xs["nb"][hi, :n_units] = nb_all[order]
-        xs["u_tw"][hi, :n_units] = u_tw_all[order]
-        xs["u_oj"][hi, :n_units] = u_oj_all[order]
-        xs["u_fd"][hi, :n_units] = u_fd_all[order]
-    MEMO_STATS["misses"] += memo_misses
-    MEMO_STATS["hits"] += H * n_units - memo_misses
+        orders: list[np.ndarray] = []
+        memo_misses = 0
+        for hi, h in enumerate(heuristics):
+            order = np.asarray(_sort_order(h, table, unit_indices),
+                               dtype=np.intp)
+            orders.append(order)
+            xs["ti"][hi, :n_units] = ti_all[order]
+            xs["hv_id"][hi, :n_units] = hv_id_all[order]
+            xs["valid"][hi, :n_units] = True
+            g = gid_all[order]
+            nr = xs["new_run"][hi, :n_units]
+            nr[0] = True
+            np.not_equal(g[1:], g[:-1], out=nr[1:])
+            memo_misses += int(nr.sum())
+            s = sig_all[order]
+            xs["sig"][hi, :n_units] = s
+            xs["ready_s"][hi, :n_units] = ready_arr[s]
+            xs["shared_s"][hi, :n_units] = shared_arr[s]
+            xs["nb"][hi, :n_units] = nb_all[order]
+            xs["u_tw"][hi, :n_units] = u_tw_all[order]
+            xs["u_oj"][hi, :n_units] = u_oj_all[order]
+            xs["u_fd"][hi, :n_units] = u_fd_all[order]
+        MEMO_STATS["misses"] += memo_misses
+        MEMO_STATS["hits"] += H * n_units - memo_misses
 
-    # per-task (E,) rows enter the scan as gathers into these small
-    # constant tables (profile rows / transfer signatures / hop vectors)
-    # rather than as (H, T, E) streams — same doubles, ~E× less traffic
-    P = pops.bucket_pow2(rtT.shape[0], minimum=1)
-    rt_tab = np.zeros((P, E))
-    en_tab = np.zeros((P, E))
-    rt_tab[:rtT.shape[0], :n_ep] = rtT
-    en_tab[:enT.shape[0], :n_ep] = enT
-    fen_tab = np.zeros(P)
-    frt_tab = np.zeros(P)
-    fen_tab[:len(en_mean)] = en_mean
-    frt_tab[:len(rt_mean)] = rt_mean
-    add_tab = np.zeros((S, E))
-    add_tab[:n_sigs] = np.stack(add_rows)
-    V = pops.bucket_pow2(len(hv_rows))
-    hv_tab = np.zeros((V, E))
-    hv_tab[:len(hv_rows)] = np.stack(hv_rows)
+        # per-task (E,) rows enter the scan as gathers into these small
+        # constant tables (profile rows / transfer signatures / hop vectors)
+        # rather than as (H, T, E) streams — same doubles, ~E× less traffic
+        P = pops.bucket_pow2(rtT.shape[0], minimum=1)
+        rt_tab = np.zeros((P, E))
+        en_tab = np.zeros((P, E))
+        rt_tab[:rtT.shape[0], :n_ep] = rtT
+        en_tab[:enT.shape[0], :n_ep] = enT
+        fen_tab = np.zeros(P)
+        frt_tab = np.zeros(P)
+        fen_tab[:len(en_mean)] = en_mean
+        frt_tab[:len(rt_mean)] = rt_mean
+        add_tab = np.zeros((S, E))
+        add_tab[:n_sigs] = np.stack(add_rows)
+        V = pops.bucket_pow2(len(hv_rows))
+        hv_tab = np.zeros((V, E))
+        hv_tab[:len(hv_rows)] = np.stack(hv_rows)
 
-    f64 = np.float64
-    consts = {
-        "idle_bt": padv(idle_bt),
-        "su_bt": padv(su_bt),
-        "qd": padv(qd_vec),
-        "rates": padv(rates_v),
-        "wt": padv(wt_v),
-        "alive": np.concatenate([alive_v, np.zeros(E - n_ep, dtype=bool)]),
-        "rt_tab": rt_tab, "en_tab": en_tab,
-        "fen_tab": fen_tab, "frt_tab": frt_tab,
-        "add_tab": add_tab, "hv_tab": hv_tab,
-        "scalars": {
-            "a1": f64(a1), "b1": f64(b1), "g1": f64(g1),
-            "idle_on_sum": f64(idle_on_sum), "w_idle_on": f64(w_idle_on),
-            "lam_b1": f64(lam_b1), "lam_a1": f64(lam_a1),
-            "alpha": f64(alpha), "sf1": f64(sf1), "sf2": f64(sf2),
-            "f_beta": f64(f_beta), "f_mu": f64(f_mu),
-        },
-    }
-    init = {
-        "mins": tile(mins0), "slots": tile(slots0), "first": tile(first0),
-        "last": tile(last0), "dyn": tile(dyn0), "const": tile(padv(const0)),
-        "const_g": tile(padv(const_g0)),
-        "e_base": np.zeros((H, E)), "nl_r": np.zeros((H, E)),
-        "g_base_r": np.zeros((H, E)), "lk_r": np.zeros((H, E)),
-        "fw_r": np.zeros((H, E)), "staged": tile(staged0),
-        "c_cur": np.full(H, c_cur0), "tj": np.full(H, base.transfer_j),
-        "c_sum_b": np.zeros(H), "tj_b": np.zeros(H),
-        "cg_sum_b": np.zeros(H),
-    }
+        f64 = np.float64
+        consts = {
+            "idle_bt": padv(idle_bt),
+            "su_bt": padv(su_bt),
+            "qd": padv(qd_vec),
+            "rates": padv(rates_v),
+            "wt": padv(wt_v),
+            "alive": np.concatenate([alive_v, np.zeros(E - n_ep, dtype=bool)]),
+            "rt_tab": rt_tab, "en_tab": en_tab,
+            "fen_tab": fen_tab, "frt_tab": frt_tab,
+            "add_tab": add_tab, "hv_tab": hv_tab,
+            "scalars": {
+                "a1": f64(a1), "b1": f64(b1), "g1": f64(g1),
+                "idle_on_sum": f64(idle_on_sum), "w_idle_on": f64(w_idle_on),
+                "lam_b1": f64(lam_b1), "lam_a1": f64(lam_a1),
+                "alpha": f64(alpha), "sf1": f64(sf1), "sf2": f64(sf2),
+                "f_beta": f64(f_beta), "f_mu": f64(f_mu),
+            },
+        }
+        init = {
+            "mins": tile(mins0), "slots": tile(slots0), "first": tile(first0),
+            "last": tile(last0), "dyn": tile(dyn0), "const": tile(padv(const0)),
+            "const_g": tile(padv(const_g0)),
+            "e_base": np.zeros((H, E)), "nl_r": np.zeros((H, E)),
+            "g_base_r": np.zeros((H, E)), "lk_r": np.zeros((H, E)),
+            "fw_r": np.zeros((H, E)), "staged": tile(staged0),
+            "c_cur": np.full(H, c_cur0), "tj": np.full(H, base.transfer_j),
+            "c_sum_b": np.zeros(H), "tj_b": np.zeros(H),
+            "cg_sum_b": np.zeros(H),
+        }
 
     out, (ei_y, s_y, e_y) = pops.greedy_window(n_ep, consts, init, xs)
 
-    # winner: objective recomputed from SoAState.metrics() per heuristic —
-    # the same authoritative float sequence _greedy_soa reports
-    best_hi = -1
-    best_obj = None
-    best_rec = None
-    for hi, h in enumerate(heuristics):
-        st_h = base.clone(keep_timeline=False)
-        free, offsets = st_h.free, st_h.offsets
-        for ei in range(n_ep):
-            cores = offsets[ei + 1] - offsets[ei]
-            free[offsets[ei]:offsets[ei + 1]] = out["slots"][hi, ei, :cores]
-        st_h.first = out["first"][hi, :n_ep].copy()
-        st_h.last = out["last"][hi, :n_ep].copy()
-        st_h.dyn = out["dyn"][hi, :n_ep].copy()
-        st_h.transfer_j = float(out["tj"][hi])
-        e_tot, c_max, tjv = st_h.metrics()
-        obj_f = alpha * e_tot / sf1 + (1 - alpha) * c_max / sf2
-        carbon_g = None
-        if carbon is not None:
-            carbon_g = state_carbon_g(st_h, carbon.rates)
-            obj_f = obj_f + carbon.gamma * carbon_g / sf3
-        if best_obj is None or obj_f < best_obj:
-            best_hi, best_obj = hi, obj_f
-            best_rec = (st_h, obj_f, e_tot, c_max, tjv, carbon_g)
+    with span("winner"):
+        # winner: objective recomputed from SoAState.metrics() per heuristic —
+        # the same authoritative float sequence _greedy_soa reports
+        best_hi = -1
+        best_obj = None
+        best_rec = None
+        for hi, h in enumerate(heuristics):
+            st_h = base.clone(keep_timeline=False)
+            free, offsets = st_h.free, st_h.offsets
+            for ei in range(n_ep):
+                cores = offsets[ei + 1] - offsets[ei]
+                free[offsets[ei]:offsets[ei + 1]] = out["slots"][hi, ei, :cores]
+            st_h.first = out["first"][hi, :n_ep].copy()
+            st_h.last = out["last"][hi, :n_ep].copy()
+            st_h.dyn = out["dyn"][hi, :n_ep].copy()
+            st_h.transfer_j = float(out["tj"][hi])
+            e_tot, c_max, tjv = st_h.metrics()
+            obj_f = alpha * e_tot / sf1 + (1 - alpha) * c_max / sf2
+            carbon_g = None
+            if carbon is not None:
+                carbon_g = state_carbon_g(st_h, carbon.rates)
+                obj_f = obj_f + carbon.gamma * carbon_g / sf3
+            if best_obj is None or obj_f < best_obj:
+                best_hi, best_obj = hi, obj_f
+                best_rec = (st_h, obj_f, e_tot, c_max, tjv, carbon_g)
 
-    st_w, obj_f, e_tot, c_max, tjv, carbon_g = best_rec
-    h_name = heuristics[best_hi]
-    assignments: dict[str, str] = {}
-    timeline = dict(base.timeline)
-    for t0, ei_v, s_v, e_v in zip(
-        (units[i][0] for i in orders[best_hi]), ei_y[best_hi, :n_units],
-        s_y[best_hi, :n_units], e_y[best_hi, :n_units],
-    ):
-        assignments[t0.id] = names[int(ei_v)]
-        timeline[t0.id] = (float(s_v), float(e_v))
-    st_w.timeline = timeline
-    st_w.cached = set(base.cached)
-    staged_out = out["staged"][best_hi]
-    for si in range(1, n_sigs):
-        if not shared_list[si]:
-            continue
-        row0, rowf, keys = staged_rows[si], staged_out[si], keys_list[si]
-        for ei in range(n_ep):
-            if rowf[ei] and not row0[ei] and keys[ei] is not None:
-                st_w.cached.add(keys[ei])
-    sched = Schedule(assignments, obj_f, e_tot, c_max, tjv, h_name,
-                     timeline, carbon_g=carbon_g)
-    if heap_state is not None:
-        st_w.write_back(heap_state)
-        sched.timeline = dict(sched.timeline)
-    elif state is not None:
-        state.replace_with(st_w)
-        sched.timeline = dict(sched.timeline)
+    with span("commit") as sp:
+        st_w, obj_f, e_tot, c_max, tjv, carbon_g = best_rec
+        h_name = heuristics[best_hi]
+        assignments: dict[str, str] = {}
+        timeline = dict(base.timeline)
+        for t0, ei_v, s_v, e_v in zip(
+            (units[i][0] for i in orders[best_hi]), ei_y[best_hi, :n_units],
+            s_y[best_hi, :n_units], e_y[best_hi, :n_units],
+        ):
+            assignments[t0.id] = names[int(ei_v)]
+            timeline[t0.id] = (float(s_v), float(e_v))
+        st_w.timeline = timeline
+        st_w.cached = set(base.cached)
+        staged_out = out["staged"][best_hi]
+        for si in range(1, n_sigs):
+            if not shared_list[si]:
+                continue
+            row0, rowf, keys = staged_rows[si], staged_out[si], keys_list[si]
+            for ei in range(n_ep):
+                if rowf[ei] and not row0[ei] and keys[ei] is not None:
+                    st_w.cached.add(keys[ei])
+        sched = Schedule(assignments, obj_f, e_tot, c_max, tjv, h_name,
+                         timeline, carbon_g=carbon_g)
+        if heap_state is not None:
+            st_w.write_back(heap_state)
+            sched.timeline = dict(sched.timeline)
+        elif state is not None:
+            state.replace_with(st_w)
+            sched.timeline = dict(sched.timeline)
+        sp.set_metadata(timeline_len=len(sched.timeline))
     return sched
 
 

@@ -33,6 +33,7 @@ so no ``inf - inf`` NaN can poison a decision.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 
@@ -41,36 +42,83 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core.spans import span
 from repro.kernels import dispatch
 from repro.kernels.placement import kernel as _kernel
 from repro.kernels.placement import ref as _ref
 
-#: JIT compile accounting: ``greedy_window`` times the first call of each
-#: (shape, backend) signature — compile + one execution — so benchmark
-#: harnesses can report compile cost separately and keep warm percentiles
-#: clean of first-flush compiles.  Cumulative; reset with
-#: :func:`reset_compile_stats`.
-COMPILE_STATS = {"compiles": 0, "seconds": 0.0}
+#: The placement scan's function name.  Its program runs as
+#: ``jit__greedy_scan(<hash>)`` in a profiler trace, and the benchmark's
+#: trace readers find the scan by this string.
+SCAN_PROGRAM = "_greedy_scan"
+
+#: Scan program accounting over the first call of each (shape, backend)
+#: signature of ``greedy_window``: ``compiles`` counts backend compiles of
+#: the scan, ``cache_loads`` the persistent compile cache's hits, and
+#: ``seconds`` the wall time of those first calls (a compile or a cache
+#: load, plus one execution), so harnesses report set-up apart from warm
+#: windows.  Cumulative; reset with :func:`reset_compile_stats`.
+COMPILE_STATS = {"compiles": 0, "cache_loads": 0, "seconds": 0.0}
 
 #: ``engine="jax"`` window accounting: ``device`` counts windows placed by
 #: :func:`greedy_window`, ``soa`` counts windows the jax engine handed to
 #: the host SoA greedy because the fused scan cannot express them
-#: (clustered units, multi-input tasks).  Cumulative; reset with
-#: :func:`reset_window_stats`.
-WINDOW_STATS = {"device": 0, "soa": 0}
+#: (clustered units, multi-input tasks).  ``h2d_bytes``/``h2d_arrays`` and
+#: ``d2h_bytes``/``d2h_arrays`` count what the device windows moved to the
+#: device and read back.  Cumulative; reset with :func:`reset_window_stats`.
+WINDOW_STATS = {"device": 0, "soa": 0, "h2d_bytes": 0, "h2d_arrays": 0,
+                "d2h_bytes": 0, "d2h_arrays": 0}
 
 _seen_signatures: set[tuple] = set()
+
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def reset_compile_stats() -> None:
     COMPILE_STATS["compiles"] = 0
+    COMPILE_STATS["cache_loads"] = 0
     COMPILE_STATS["seconds"] = 0.0
     _seen_signatures.clear()
 
 
 def reset_window_stats() -> None:
-    WINDOW_STATS["device"] = 0
-    WINDOW_STATS["soa"] = 0
+    for k in WINDOW_STATS:
+        WINDOW_STATS[k] = 0
+
+
+class _ScanCompiles:
+    """Backend compiles of the scan and its persistent-cache hits, from the
+    ``jax.monitoring`` events seen while it is open.  JAX reports a cache
+    hit as a backend compile too, recording the hit first and the compile
+    (with the function's name) when it returns."""
+
+    def __init__(self):
+        self.compiles = 0
+        self.loads = 0
+        self._hit = False
+
+    def _duration(self, event, duration, **kw):
+        if event == _BACKEND_COMPILE_EVENT:
+            if SCAN_PROGRAM in str(kw.get("fun_name", "")):
+                if self._hit:
+                    self.loads += 1
+                else:
+                    self.compiles += 1
+            self._hit = False
+
+    def _event(self, event, **kw):
+        if event == _CACHE_HIT_EVENT:
+            self._hit = True
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._duration)
+        jax.monitoring.unregister_event_listener(self._event)
 
 
 def bucket_pow2(n: int, minimum: int = 1) -> int:
@@ -362,8 +410,11 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     with a leading heuristic axis.  ``xs``: per-task streams, shape
     ``(H, T_pad, ...)``, permuted per heuristic.  Returns
     ``(final_carry, (ei, start, end))`` as numpy arrays, and maintains
-    :data:`COMPILE_STATS` (first call per shape signature is counted —
-    and timed — as a compile).
+    :data:`COMPILE_STATS` (the first call per shape signature is timed and
+    split into compiles and cache loads) and :data:`WINDOW_STATS`.  The
+    transfer up, the scan and the readback are spans ``gf.h2d``,
+    ``gf.scan`` and ``gf.d2h``; the transfers carry ``bytes`` and
+    ``arrays``.
     """
     use_kernel = dispatch.placement_use_pallas()
     sig = (
@@ -373,29 +424,41 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
         tuple(sorted((k, np.shape(v)) for k, v in consts.items()
                      if k != "scalars")),
     )
-    t0 = None
-    if sig not in _seen_signatures:
-        _seen_signatures.add(sig)
-        t0 = time.perf_counter()
+    first = sig not in _seen_signatures
+    _seen_signatures.add(sig)
+    t0 = time.perf_counter()
+    seen = _ScanCompiles() if first else contextlib.nullcontext()
+    host = jax.tree_util.tree_leaves((xs, init, consts))
+    h2d_bytes = sum(v.nbytes for v in host)
     # x64 is scoped to the placement scan (trace + execute) rather than
     # enabled process-wide: the parity contract is float64, but sibling
     # kernels in this package trace float32 and must stay untouched
-    with jax.enable_x64(True):
-        jxs = jax.tree_util.tree_map(jnp.asarray, xs)
-        jinit = jax.tree_util.tree_map(jnp.asarray, init)
-        jconsts = jax.tree_util.tree_map(jnp.asarray, consts)
-        carry, ys = _greedy_scan(jconsts, _as_tuple_carry(jinit), jxs,
-                                 n_ep=n_ep, use_kernel=use_kernel)
-        carry = jax.block_until_ready(carry)
-    if t0 is not None:
-        COMPILE_STATS["compiles"] += 1
+    with seen, jax.enable_x64(True):
+        with span("h2d", bytes=h2d_bytes, arrays=len(host)):
+            jxs = jax.tree_util.tree_map(jnp.asarray, xs)
+            jinit = jax.tree_util.tree_map(jnp.asarray, init)
+            jconsts = jax.tree_util.tree_map(jnp.asarray, consts)
+        with span("scan"):
+            carry, ys = _greedy_scan(jconsts, _as_tuple_carry(jinit), jxs,
+                                     n_ep=n_ep, use_kernel=use_kernel)
+            carry = jax.block_until_ready(carry)
+    if first:
+        COMPILE_STATS["compiles"] += seen.compiles
+        COMPILE_STATS["cache_loads"] += seen.loads
         COMPILE_STATS["seconds"] += time.perf_counter() - t0
-    WINDOW_STATS["device"] += 1
     names = ("mins", "slots", "first", "last", "dyn", "const", "const_g",
              "e_base", "nl_r", "g_base_r", "lk_r", "fw_r", "staged",
              "c_cur", "tj", "c_sum_b", "tj_b", "cg_sum_b")
-    out = {k: np.asarray(v) for k, v in zip(names, carry)}
-    ei, start, end = (np.asarray(v) for v in ys)
+    dev = (*carry, *ys)
+    d2h_bytes = sum(v.nbytes for v in dev)
+    with span("d2h", bytes=d2h_bytes, arrays=len(dev)):
+        out = {k: np.asarray(v) for k, v in zip(names, carry)}
+        ei, start, end = (np.asarray(v) for v in ys)
+    WINDOW_STATS["device"] += 1
+    WINDOW_STATS["h2d_bytes"] += h2d_bytes
+    WINDOW_STATS["h2d_arrays"] += len(host)
+    WINDOW_STATS["d2h_bytes"] += d2h_bytes
+    WINDOW_STATS["d2h_arrays"] += len(dev)
     return out, (ei, start, end)
 
 

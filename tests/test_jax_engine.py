@@ -239,18 +239,24 @@ def test_explicit_jax_raises_when_ops_cannot_import(monkeypatch):
 
 def test_window_stats_count_device_and_soa_windows():
     """A clustered window is handed to soa and counted as such; a plain
-    window is placed by the scan and counted as a device window."""
+    window is placed by the scan and counted as a device window, with the
+    arrays it moved: 11 task streams, 18 carry seeds, 12 constant tables
+    and 12 scalars up; the 18 carry registers and 3 outputs back."""
     tasks, eps, store, tm = _setup(n_per=4)
     pops.reset_window_stats()
     cluster_mhra(tasks, eps, store, tm, alpha=0.5, max_cluster_size=16,
                  engine="jax")
-    assert pops.WINDOW_STATS == {"device": 0, "soa": 1}
+    zero = {"h2d_bytes": 0, "h2d_arrays": 0, "d2h_bytes": 0, "d2h_arrays": 0}
+    assert pops.WINDOW_STATS == {"device": 0, "soa": 1, **zero}
     mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
-    assert pops.WINDOW_STATS == {"device": 1, "soa": 1}
+    one = dict(pops.WINDOW_STATS)
+    assert (one["device"], one["soa"]) == (1, 1)
+    assert (one["h2d_arrays"], one["d2h_arrays"]) == (53, 21)
+    assert one["h2d_bytes"] > 0 and one["d2h_bytes"] > 0
     mhra(tasks, eps, store, tm, alpha=0.5, engine="soa")
-    assert pops.WINDOW_STATS == {"device": 1, "soa": 1}
+    assert pops.WINDOW_STATS == one
     pops.reset_window_stats()
-    assert pops.WINDOW_STATS == {"device": 0, "soa": 0}
+    assert pops.WINDOW_STATS == {"device": 0, "soa": 0, **zero}
 
 
 def test_auto_batch_escalates_to_jax_and_matches_soa(monkeypatch):
