@@ -692,79 +692,168 @@ def _normalizers(tasks, endpoints, per_ep, transfer, carbon=None
     return max(sf1, 1e-9), max(sf2, 1e-9), max(sf3, 1e-9)
 
 
+#: Fleets this wide and wider run the normalizers' list scheduling on one
+#: slot matrix for every endpoint at once; narrower fleets run it endpoint
+#: by endpoint on a heap, where a NumPy call per task costs more than the
+#: few heap steps it replaces.  Measured on a TPU v5e host: the heap wins
+#: up to 12 endpoints, the two tie at 16, the matrix wins at 32 (PERF.md).
+NORMALIZER_MATRIX_MIN_ENDPOINTS = 16
+
+
 def _normalizers_fast(tasks, endpoints, table: PredictionTable, transfer,
                       carbon=None) -> tuple[float, float, float]:
-    """Same SF1/SF2 values as :func:`_normalizers` (operation-identical
-    float sequence) computed from the prediction table's flat rows instead
-    of nested Prediction dicts."""
-    heappop, heappush = heapq.heappop, heapq.heappush
-    n = len(tasks)
-    nbs = [t.not_before for t in tasks]
-    sf1 = sf2 = sf3 = 0.0
-    for ei, ep in enumerate(endpoints):
-        name = ep.name
-        # transfer delta of the whole workload as one unit, fresh cache
-        tj, t_bytes, t_files = 0.0, 0.0, 0
-        seen: set[tuple[str, str]] = set()
+    """The SF1/SF2/SF3 doubles of :func:`_normalizers`, equal and not just
+    close, from one pass over the window for the whole fleet.
+
+    Shared-input deduplication does not depend on the destination: the key
+    ``src:n_files:bytes`` names its source, so the inputs that count for
+    endpoint X are the window's deduplicated inputs less those whose source
+    is X.  The window's inputs are read once; each endpoint then adds the
+    kept rows in window order, the same additions as a per-endpoint scan.
+    The list scheduling of the window on each endpoint alone runs in
+    :func:`_runs_by_matrix` or, on narrow fleets, :func:`_runs_by_heap`;
+    the single-endpoint ``metrics()`` and carbon arithmetic follow per
+    endpoint.  The ``gf.normalizers`` span reports ``inputs`` (task inputs
+    read) and ``rows`` (transfer rows kept after deduplication).
+    """
+    with span("normalizers") as sp:
+        rows: list[tuple[str, int, float]] = []
+        seen: set[str] = set()
+        n_inputs = 0
         for t in tasks:
+            n_inputs += len(t.inputs)
             for src, n_files, nbytes, shared in t.inputs:
+                if shared:
+                    key = f"{src}:{n_files}:{nbytes}"
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                rows.append((src, n_files, nbytes))
+        sp.set_metadata(inputs=n_inputs, rows=len(rows))
+
+        tjs, ready = [], []
+        for ep in endpoints:
+            # transfer delta of the whole workload as one unit, fresh cache
+            name = ep.name
+            tj, t_bytes, t_files = 0.0, 0.0, 0
+            for src, n_files, nbytes in rows:
                 if src == name:
                     continue
-                key = (name, f"{src}:{n_files}:{nbytes}")
-                if shared and key in seen:
-                    continue
-                if shared:
-                    seen.add(key)
                 tj += transfer.hops(src, name) * nbytes * E_INC_J_PER_BYTE
                 t_bytes += nbytes
                 t_files += n_files
-        ready = transfer.predict_seconds(t_files, t_bytes)
-        if ep.has_batch_scheduler:
-            ready += ep.queue_delay_s
-        row_rt, row_en = table.rt_rows[ei], table.en_rows[ei]
+            r = transfer.predict_seconds(t_files, t_bytes)
+            if ep.has_batch_scheduler:
+                r += ep.queue_delay_s
+            tjs.append(tj)
+            ready.append(r)
+
+        nbs = [t.not_before for t in tasks]
+        if tasks and len(endpoints) >= NORMALIZER_MATRIX_MIN_ENDPOINTS:
+            runs = _runs_by_matrix(endpoints, table, ready, nbs)
+        else:
+            runs = _runs_by_heap(endpoints, table, ready, nbs)
+
+        sf1 = sf2 = sf3 = 0.0
+        for ei, (ep, e, (first, last, dyn)) in enumerate(zip(endpoints, tjs, runs)):
+            # single-endpoint metrics(), same accumulation order
+            c = last if last > 0.0 else 0.0
+            if first is None:
+                if not ep.has_batch_scheduler:
+                    e += ep.idle_power_w * c
+            else:
+                if ep.has_batch_scheduler:
+                    e += ep.idle_power_w * (last - first) + ep.startup_energy_j
+                else:
+                    e += ep.idle_power_w * c
+                e += dyn
+            sf1, sf2 = max(sf1, e), max(sf2, c)
+            if carbon is not None:
+                # single-endpoint _carbon_terms_g, same expression grouping
+                w = carbon.rates[ei]
+                if first is None:
+                    g = w * (ep.idle_power_w * c) if not ep.has_batch_scheduler else 0.0
+                elif ep.has_batch_scheduler:
+                    g = w * (ep.idle_power_w * (last - first)
+                             + ep.startup_energy_j + dyn)
+                else:
+                    g = w * (ep.idle_power_w * c + dyn)
+                sf3 = max(sf3, g)
+    return max(sf1, 1e-9), max(sf2, 1e-9), max(sf3, 1e-9)
+
+
+def _runs_by_heap(endpoints, table: PredictionTable, ready, nbs):
+    """``(first start, last end, dynamic energy)`` of the window's tasks on
+    each endpoint alone, from empty: list scheduling over the endpoint's
+    cores on a heap, one endpoint after another.  ``first`` is None for an
+    empty window."""
+    heapreplace = heapq.heapreplace
+    runs = []
+    for ei, ep in enumerate(endpoints):
+        r = ready[ei]
         slots = [0.0] * ep.cores
-        heapq.heapify(slots)
         first = None
         last = 0.0
         dyn = 0.0
-        for i in range(n):
-            start = heappop(slots)
-            if start < ready:
-                start = ready
-            if start < nbs[i]:
-                start = nbs[i]
-            end = start + row_rt[i]
-            heappush(slots, end)
+        for rt, en, nb in zip(table.rt_rows[ei], table.en_rows[ei], nbs):
+            start = slots[0]
+            if start < r:
+                start = r
+            if start < nb:
+                start = nb
+            end = start + rt
+            heapreplace(slots, end)
             if first is None or start < first:
                 first = start
             if end > last:
                 last = end
-            dyn += row_en[i]
-        # single-endpoint metrics(), same accumulation order
-        c = last if last > 0.0 else 0.0
-        e = tj
-        if first is None:
-            if not ep.has_batch_scheduler:
-                e += ep.idle_power_w * c
-        else:
-            if ep.has_batch_scheduler:
-                e += ep.idle_power_w * (last - first) + ep.startup_energy_j
-            else:
-                e += ep.idle_power_w * c
-            e += dyn
-        sf1, sf2 = max(sf1, e), max(sf2, c)
-        if carbon is not None:
-            # single-endpoint _carbon_terms_g, same expression grouping
-            w = carbon.rates[ei]
-            if first is None:
-                g = w * (ep.idle_power_w * c) if not ep.has_batch_scheduler else 0.0
-            elif ep.has_batch_scheduler:
-                g = w * (ep.idle_power_w * (last - first)
-                         + ep.startup_energy_j + dyn)
-            else:
-                g = w * (ep.idle_power_w * c + dyn)
-            sf3 = max(sf3, g)
-    return max(sf1, 1e-9), max(sf2, 1e-9), max(sf3, 1e-9)
+            dyn += en
+        runs.append((first, last, dyn))
+    return runs
+
+
+def _runs_by_matrix(endpoints, table: PredictionTable, ready, nbs):
+    """What :func:`_runs_by_heap` returns for a window of one task or more,
+    every endpoint advanced one task at a time.
+
+    Popping a heap's smallest slot and pushing the task's end leaves the
+    multiset that overwriting the smallest slot in place leaves (the
+    identity :class:`SoAState` rests on), so the heaps become one
+    (endpoints, max cores) slot matrix padded with +inf.  Slots start at
+    ``max(0, ready)`` rather than 0: with no negative runtime every later
+    slot is at least ``ready`` too, so the smallest slot is already the
+    start clamped to ``ready``; the clamp to ``not_before`` is an identity
+    when no task's ``not_before`` lies above the smallest ``ready``.
+    ``dyn`` is each energy row summed in task order, as the heap adds it.
+    """
+    rtT, _ = table.transposed()
+    n, n_ep = rtT.shape
+    ready = np.array(ready)
+    width = max(ep.cores for ep in endpoints)
+    slots = np.full((n_ep, width), np.inf)
+    floor = np.maximum(0.0, ready)
+    for ei, ep in enumerate(endpoints):
+        slots[ei, :ep.cores] = floor[ei]
+    flat = slots.reshape(-1)
+    base = np.arange(n_ep) * width
+    clamp_ready = bool((rtT < 0.0).any())
+    clamp_nb = max(nbs) > ready.min()
+    starts = np.empty((n, n_ep))
+    ends = np.empty((n, n_ep))
+    argmin, add, maximum = slots.argmin, np.add, np.maximum
+    for rt, nb, start, end in zip(rtT, nbs, starts, ends):
+        k = argmin(1)
+        k += base
+        start[:] = flat[k]
+        if clamp_ready:
+            maximum(start, ready, start)
+        if clamp_nb:
+            maximum(start, nb, start)
+        add(start, rt, end)
+        flat[k] = end
+    return list(zip(starts.min(axis=0).tolist(),
+                    np.maximum(ends.max(axis=0), 0.0).tolist(),
+                    np.cumsum(table.en, axis=1)[:, -1].tolist()))
 
 
 def _warm_terms(warm: WarmWeights, alpha: float, sf1: float, sf2: float):
@@ -891,9 +980,7 @@ def mhra(
         else:
             units = [[tasks[i] for i in c] for c in clusters]
         unit_indices = [[table.index[t.id] for t in u] for u in units]
-    with span("normalizers"):
-        sf1, sf2, sf3 = _normalizers_fast(tasks, endpoints, table, transfer,
-                                          carbon)
+    sf1, sf2, sf3 = _normalizers_fast(tasks, endpoints, table, transfer, carbon)
 
     if engine in ("jax", "soa"):
         search = _mhra_jax if engine == "jax" else _mhra_soa

@@ -113,6 +113,22 @@ def test_a_device_window_counts_the_bytes_of_the_arrays_it_moves(tmp_path,
                      "gf.scan", "gf.d2h", "gf.winner", "gf.commit", "gf.release"]
 
 
+def test_normalizers_count_the_inputs_read_and_the_rows_kept(tmp_path):
+    """Shared-input deduplication runs once a window: a window whose tasks
+    share one input keeps one transfer row of its inputs; a private input
+    is a row of its own."""
+    eps, store = _fleet()
+    shared = (eps[0].name, 1, 2e8, True)
+    private = (eps[1].name, 3, 1e6, False)
+    tasks = [TaskSpec(id=f"t{i}", fn=SEBS_FUNCTIONS[i % len(SEBS_FUNCTIONS)],
+                      inputs=(shared, private) if i == 0 else (shared,))
+             for i in range(12)]
+    evs = _traced(tmp_path, lambda: mhra(tasks, eps, store, TransferModel(eps),
+                                         engine="soa"))
+    norm, = [ev[3] for ev in evs if ev[0] == "gf.normalizers"]
+    assert norm == {"inputs": 13, "rows": 2}
+
+
 def test_compile_stats_tell_a_compile_from_a_cache_load(tmp_path):
     eps, store = _fleet()
     tm = TransferModel(eps)
