@@ -2,10 +2,11 @@
 
 ``greedy_window`` runs one arrival window's whole greedy placement — all
 ordering heuristics at once — as a jit-compiled ``lax.scan`` over the
-window's tasks, vmapped across heuristics.  Each scan step scores the
-full candidate fleet as one fused vector pass over the SoA engine's
-carry registers and commits via a first-min argmin, reproducing
-``_greedy_soa``'s float sequences double for double:
+window's tasks whose step holds every heuristic (the per-heuristic body
+vmapped inside the step).  Each scan step scores the full candidate
+fleet as one fused vector pass over the SoA engine's carry registers and
+commits via a first-min argmin, reproducing ``_greedy_soa``'s float
+sequences double for double:
 
 - The per-step objective is *recomputed* from carried registers
   (``e_base``/``nl``/term registers + the frozen run basis) instead of
@@ -16,7 +17,10 @@ carry registers and commits via a first-min argmin, reproducing
   on a run boundary the basis scalars (``const`` sums, the transfer
   baseline) refresh — using :func:`ref.pairwise_sum` so the in-scan sum
   matches ``np.sum``'s association bitwise — and stay frozen within the
-  run, exactly like the SoA engine's memo basis.
+  run, exactly like the SoA engine's memo basis.  The pass that computes
+  the basis runs only on steps where some heuristic starts a run (a
+  ``lax.cond`` on ``any(new_run)`` over the heuristic axis); elsewhere
+  every heuristic keeps its basis, which the pass would have kept too.
 - Disabled term registers (carbon/lookahead/fairness/warm) enter as
   zeros with zero weights; ``+0.0`` is bitwise-inert here, so one traced
   program covers every register combination — no per-flag recompiles.
@@ -65,9 +69,13 @@ COMPILE_STATS = {"compiles": 0, "cache_loads": 0, "seconds": 0.0}
 #: the host SoA greedy because the fused scan cannot express them
 #: (clustered units, multi-input tasks).  ``h2d_bytes``/``h2d_arrays`` and
 #: ``d2h_bytes``/``d2h_arrays`` count what the device windows moved to the
-#: device and read back.  Cumulative; reset with :func:`reset_window_stats`.
+#: device and read back.  ``scan_steps`` counts the device windows' valid
+#: scan steps (tasks), ``basis_steps`` those on which some heuristic starts
+#: a run, so the scan ran its run-basis pass.  Cumulative; reset with
+#: :func:`reset_window_stats`.
 WINDOW_STATS = {"device": 0, "soa": 0, "h2d_bytes": 0, "h2d_arrays": 0,
-                "d2h_bytes": 0, "d2h_arrays": 0}
+                "d2h_bytes": 0, "d2h_arrays": 0, "basis_steps": 0,
+                "scan_steps": 0}
 
 _seen_signatures: set[tuple] = set()
 
@@ -219,7 +227,8 @@ def _score_lanes(e_base, nl, g_base, lk, fw, wt, alive, c_cur,
 
 @functools.partial(jax.jit, static_argnames=("n_ep", "use_kernel"))
 def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
-    """vmapped-over-heuristics scan; see ``greedy_window`` for the layout.
+    """One scan over the window's tasks, every heuristic in each step; see
+    ``greedy_window`` for the layout.
 
     ``n_ep`` (the *true* fleet size) is static: the run-basis scalars are
     summed over exactly the first ``n_ep`` lanes with numpy's pairwise
@@ -239,37 +248,27 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
     fen_tab, frt_tab = consts["fen_tab"], consts["frt_tab"]
     add_tab, hv_tab = consts["add_tab"], consts["hv_tab"]
 
-    def step(carry, x):
-        # per-endpoint registers ride stacked ((6, E) commit-updated, (5, E)
-        # run-basis) so the commit is two column scatters / two column
-        # gathers instead of ~20 per-register dynamic ops — storage layout
-        # only, every double is the one the unstacked carry would hold
-        (base_regs, slots, run_regs, staged, c_cur, tj, c_sum_b, tj_b,
-         cg_sum_b) = carry
+    def basis(base_regs, staged, tj, regs, x):
+        """One heuristic's full vectorized pass (the SoA miss pass, op for
+        op), taken into the run basis only where ``new_run`` is set."""
+        run_regs, c_sum_b, cg_sum_b, tj_b = regs
         mins, first, last, dyn, const, const_g = base_regs
-        sig = x["sig"]
+        sig, ti = x["sig"], x["ti"]
         st_row = staged[sig]
         # per-task (E,) rows are gathered from small constant tables
         # instead of streamed as (H, T, E) xs — same doubles, a fraction
         # of the memory traffic on deep windows
-        ti = x["ti"]
         add_row = add_tab[sig]
         hv_row = hv_tab[x["hv_id"]]
         rt_row, en_row = rt_tab[ti], en_tab[ti]
-        ready_s = x["ready_s"]
-        shared_s = x["shared_s"]
         eff_add = jnp.where(st_row, 0.0, add_row)
-        eff_ready = jnp.where(st_row, 0.0, ready_s) + qd
-        nb = x["nb"]
-
-        # ---- full vectorized pass (the SoA miss pass, op for op);
-        # selected into the carry only on run boundaries -------------------
+        eff_ready = jnp.where(st_row, 0.0, x["ready_s"]) + qd
         c_sum_f = _ref.pairwise_sum(const, n_ep)
         cg_sum_f = _ref.pairwise_sum(const_g, n_ep)
         static = c_sum_f - const
         static_g = cg_sum_f - const_g
         start = jnp.maximum(mins, eff_ready)
-        start = jnp.maximum(start, nb)   # bitwise no-op when nb <= 0
+        start = jnp.maximum(start, x["nb"])   # bitwise no-op when nb <= 0
         end = start + rt_row
         nf = jnp.minimum(first, start)
         nl = jnp.maximum(last, end)
@@ -297,12 +296,14 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
             jnp.stack([e_base_f, nl, g_base_f, lk_f, fw_f]),
             run_regs,
         )
-        e_base, nl_r, g_base_r, lk_r, fw_r = run_regs
-        c_sum_b = jnp.where(new_run, c_sum_f, c_sum_b)
-        cg_sum_b = jnp.where(new_run, cg_sum_f, cg_sum_b)
-        tj_b = jnp.where(new_run, tj, tj_b)
+        return (run_regs, jnp.where(new_run, c_sum_f, c_sum_b),
+                jnp.where(new_run, cg_sum_f, cg_sum_b),
+                jnp.where(new_run, tj, tj_b))
 
-        # ---- fused score + first-min argmin ------------------------------
+    def score_commit(base_regs, slots, staged, c_cur, tj, regs, x):
+        """One heuristic's fused score, first-min argmin and commit."""
+        run_regs, c_sum_b, cg_sum_b, tj_b = regs
+        e_base, nl_r, g_base_r, lk_r, fw_r = run_regs
         if use_kernel:
             scalars = jnp.stack(
                 [c_cur, idle_on_sum, a1, b1, g1, w_idle_on]
@@ -323,24 +324,30 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
         # value is gated on ``valid`` (pad steps write the old value back
         # bitwise) — an O(1) guard per scatter instead of a full
         # carry-tree where-select, whose O(E*C) slots copy per step
-        # dominated the scan on deep windows ------------------------------
+        # dominated the scan on deep windows.  The task's table rows are
+        # read at the committed lane only: the same doubles as the rows'
+        # entries ------------------------------------------------------------
         valid = x["valid"]
 
         def sel(new_v, old_v):
             return jnp.where(valid, new_v, old_v)
 
-        ready_e = eff_ready[ei]
-        tj2 = sel(tj + eff_add[ei], tj)
-        staged_e2 = st_row[ei] | shared_s
-        staged2 = staged.at[sig, ei].set(sel(staged_e2, st_row[ei]))
+        sig, ti, ready_s, nb = x["sig"], x["ti"], x["ready_s"], x["nb"]
+        st_e = staged[sig, ei]
+        add_e = add_tab[sig, ei]
+        rt_e, en_e = rt_tab[ti, ei], en_tab[ti, ei]
+        ready_e = jnp.where(st_e, 0.0, ready_s) + qd[ei]
+        tj2 = sel(tj + jnp.where(st_e, 0.0, add_e), tj)
+        staged_e2 = st_e | x["shared_s"]
+        staged2 = staged.at[sig, ei].set(sel(staged_e2, st_e))
         bcol = base_regs[:, ei]       # one gather for all six registers
         mins_e, first_e, last_e, dyn_e, const_e, const_g_e = bcol
         start_v = jnp.maximum(mins_e, ready_e)
         start_v = jnp.maximum(start_v, nb)
-        end_v = start_v + rt_row[ei]
+        end_v = start_v + rt_e
         nf_v = jnp.minimum(start_v, first_e)
         nl_v = jnp.maximum(end_v, last_e)
-        nd_v = dyn_e + en_row[ei]
+        nd_v = dyn_e + en_e
         row = slots[ei]
         k = jnp.argmin(row)           # first min slot, like list.index(min)
         row2 = row.at[k].set(end_v)
@@ -354,18 +361,20 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
         ready2 = jnp.where(staged_e2, 0.0, ready_s) + qd[ei]
         s2 = jnp.maximum(m2, ready2)
         s2 = jnp.maximum(s2, nb)
-        e2 = s2 + rt_row[ei]
+        e2 = s2 + rt_e
         nf2 = jnp.minimum(s2, nf_v)
         nl2 = jnp.maximum(e2, nl_v)
-        e_b = (c_sum_b - c_e) + (nd_v + en_row[ei])
+        e_b = (c_sum_b - c_e) + (nd_v + en_e)
         e_b = e_b + ((nl2 - nf2) * idle_bt[ei] + su_bt[ei])
-        e_b = e_b + jnp.where(staged_e2, 0.0, add_row[ei])
+        e_b = e_b + jnp.where(staged_e2, 0.0, add_e)
         e_b = e_b + tj_b
         g_b = (cg_sum_b - cg_e) + rates[ei] * (
             ((nl2 - nf2) * idle_bt[ei] + su_bt[ei])
-            + (nd_v + en_row[ei])
+            + (nd_v + en_e)
         )
-        lk_e = e2 * lk_c1 + hv_row[ei] * lk_c2
+        lk_c1 = lam_b1 * x["u_tw"]
+        lk_c2 = lam_a1 * x["u_oj"]
+        lk_e = e2 * lk_c1 + hv_tab[x["hv_id"], ei] * lk_c2
         # fw_r (row 4) is per-run, never refreshed by a commit
         run_regs2 = run_regs.at[:4, ei].set(
             sel(jnp.stack([e_b, nl2, g_b, lk_e]), run_regs[:4, ei])
@@ -376,30 +385,49 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
             base_regs2, slots2, run_regs2, staged2, c_cur2,
             tj2, c_sum_b, tj_b, cg_sum_b,
         )
-        ys = (ei.astype(jnp.int32), start_v, end_v)
-        return carry_out, ys
+        return carry_out, (ei.astype(jnp.int32), start_v, end_v)
 
-    def run_one(init_h, xs_h):
-        (mins, slots, first, last, dyn, const, const_g, e_base, nl_r,
-         g_base_r, lk_r, fw_r, staged, c_cur, tj, c_sum_b, tj_b,
-         cg_sum_b) = init_h
-        carry0 = (
-            jnp.stack([mins, first, last, dyn, const, const_g]), slots,
-            jnp.stack([e_base, nl_r, g_base_r, lk_r, fw_r]), staged,
-            c_cur, tj, c_sum_b, tj_b, cg_sum_b,
+    def step(carry, x):
+        # x and every carry leaf lead with the heuristic axis.  The run
+        # basis changes only on a run's first task, so the pass that
+        # computes it runs only on steps where some heuristic starts a run;
+        # the predicate is taken over heuristics outside the vmap, since a
+        # batched predicate would turn the cond into a select of both sides
+        (base_regs, slots, run_regs, staged, c_cur, tj, c_sum_b, tj_b,
+         cg_sum_b) = carry
+        regs = (run_regs, c_sum_b, cg_sum_b, tj_b)
+        regs = lax.cond(
+            jnp.any(x["new_run"]),
+            lambda: jax.vmap(basis)(base_regs, staged, tj, regs, x),
+            lambda: regs,
         )
-        # unroll a few steps per scan iteration: XLA:CPU's per-iteration
-        # dispatch overhead dominates on deep windows, and unrolling keeps
-        # the op sequence (hence every double) identical
-        carry_f, ys = lax.scan(step, carry0, xs_h, unroll=4)
-        b, slots_f, r, staged_f, c_cur_f, tj_f, csb, tjb, cgb = carry_f
-        return (
-            b[0], slots_f, b[1], b[2], b[3], b[4], b[5],
-            r[0], r[1], r[2], r[3], r[4], staged_f, c_cur_f, tj_f,
-            csb, tjb, cgb,
-        ), ys
+        return jax.vmap(score_commit)(base_regs, slots, staged, c_cur, tj,
+                                      regs, x)
 
-    return jax.vmap(run_one)(init, xs)
+    (mins, slots, first, last, dyn, const, const_g, e_base, nl_r,
+     g_base_r, lk_r, fw_r, staged, c_cur, tj, c_sum_b, tj_b,
+     cg_sum_b) = init
+    # per-endpoint registers ride stacked ((H, 6, E) commit-updated,
+    # (H, 5, E) run-basis) so the commit is two column scatters / two column
+    # gathers instead of ~20 per-register dynamic ops — storage layout
+    # only, every double is the one the unstacked carry would hold
+    carry0 = (
+        jnp.stack([mins, first, last, dyn, const, const_g], axis=1), slots,
+        jnp.stack([e_base, nl_r, g_base_r, lk_r, fw_r], axis=1), staged,
+        c_cur, tj, c_sum_b, tj_b, cg_sum_b,
+    )
+    # scan over tasks: (H, T) streams in, (H, T) outputs back
+    xs_t = jax.tree_util.tree_map(lambda v: jnp.swapaxes(v, 0, 1), xs)
+    # unroll a few steps per scan iteration: XLA:CPU's per-iteration
+    # dispatch overhead dominates on deep windows, and unrolling keeps
+    # the op sequence (hence every double) identical
+    carry_f, ys = lax.scan(step, carry0, xs_t, unroll=4)
+    b, slots_f, r, staged_f, c_cur_f, tj_f, csb, tjb, cgb = carry_f
+    return (
+        b[:, 0], slots_f, b[:, 1], b[:, 2], b[:, 3], b[:, 4], b[:, 5],
+        r[:, 0], r[:, 1], r[:, 2], r[:, 3], r[:, 4], staged_f, c_cur_f, tj_f,
+        csb, tjb, cgb,
+    ), tuple(jnp.swapaxes(y, 0, 1) for y in ys)
 
 
 def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
@@ -414,7 +442,7 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     split into compiles and cache loads) and :data:`WINDOW_STATS`.  The
     transfer up, the scan and the readback are spans ``gf.h2d``,
     ``gf.scan`` and ``gf.d2h``; the transfers carry ``bytes`` and
-    ``arrays``.
+    ``arrays``, the scan ``basis_steps`` and ``scan_steps``.
     """
     use_kernel = dispatch.placement_use_pallas()
     sig = (
@@ -430,6 +458,9 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     seen = _ScanCompiles() if first else contextlib.nullcontext()
     host = jax.tree_util.tree_leaves((xs, init, consts))
     h2d_bytes = sum(v.nbytes for v in host)
+    valid = np.asarray(xs["valid"])
+    scan_steps = int(valid.any(axis=0).sum())
+    basis_steps = int((np.asarray(xs["new_run"]) & valid).any(axis=0).sum())
     # x64 is scoped to the placement scan (trace + execute) rather than
     # enabled process-wide: the parity contract is float64, but sibling
     # kernels in this package trace float32 and must stay untouched
@@ -438,7 +469,7 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
             jxs = jax.tree_util.tree_map(jnp.asarray, xs)
             jinit = jax.tree_util.tree_map(jnp.asarray, init)
             jconsts = jax.tree_util.tree_map(jnp.asarray, consts)
-        with span("scan"):
+        with span("scan", basis_steps=basis_steps, scan_steps=scan_steps):
             carry, ys = _greedy_scan(jconsts, _as_tuple_carry(jinit), jxs,
                                      n_ep=n_ep, use_kernel=use_kernel)
             carry = jax.block_until_ready(carry)
@@ -459,6 +490,8 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     WINDOW_STATS["h2d_arrays"] += len(host)
     WINDOW_STATS["d2h_bytes"] += d2h_bytes
     WINDOW_STATS["d2h_arrays"] += len(dev)
+    WINDOW_STATS["basis_steps"] += basis_steps
+    WINDOW_STATS["scan_steps"] += scan_steps
     return out, (ei, start, end)
 
 

@@ -115,6 +115,59 @@ def test_jax_matches_soa_all_registers():
     _assert_bitwise(a, b)
 
 
+def _gate_case(case, tasks):
+    if case == "staggered_runs":
+        # function k has 3k + 2 tasks, so the runtime and energy orders cut
+        # the window into runs at different steps
+        return [TaskSpec(id=f"s{k}_{j}", fn=fn, inputs=tasks[0].inputs)
+                for k, fn in enumerate(SEBS_FUNCTIONS)
+                for j in range(3 * k + 2)]
+    if case == "every_step_a_run":
+        # distinct not_before floors: no two tasks share a run key
+        return [TaskSpec(id=t.id, fn=t.fn, inputs=t.inputs,
+                         not_before=0.25 * i) for i, t in enumerate(tasks)]
+    return tasks[:33]       # one step past 32: 31 pad steps to 64
+
+
+@pytest.mark.parametrize("case", ["staggered_runs", "every_step_a_run",
+                                  "padded_window"])
+def test_gated_run_basis_matches_soa(case, monkeypatch):
+    """The scan runs its run-basis pass only on steps where some heuristic
+    starts a run, and keeps every heuristic's basis elsewhere; placements
+    and every double stay soa's, and ``WINDOW_STATS`` counts the steps the
+    pass ran on as the host reads them from the streams.  On 12 endpoints
+    at this alpha a basis left stale where a heuristic starts a run moves
+    placements (the staggered and padded windows)."""
+    tasks, eps, store, tm = _setup(n_per=12, replicas=3)
+    tasks = _gate_case(case, tasks)
+    seen = []
+    call = pops.greedy_window
+
+    def spy(n_ep, consts, init, xs):
+        seen.append({k: np.array(xs[k]) for k in ("new_run", "valid")})
+        return call(n_ep, consts, init, xs)
+
+    monkeypatch.setattr(pops, "greedy_window", spy)
+    pops.reset_window_stats()
+    a = mhra(tasks, eps, store, tm, alpha=0.4, engine="soa")
+    b = mhra(tasks, eps, store, tm, alpha=0.4, engine="jax")
+    _assert_bitwise(a, b)
+    xs, = seen
+    starts = xs["new_run"] & xs["valid"]
+    gate = starts.any(axis=0)
+    assert pops.WINDOW_STATS["basis_steps"] == int(gate.sum())
+    assert pops.WINDOW_STATS["scan_steps"] == int(xs["valid"].any(axis=0).sum())
+    assert pops.WINDOW_STATS["scan_steps"] == len(tasks)
+    if case == "staggered_runs":
+        # the gate opens on steps where other heuristics keep their basis
+        assert (gate & ~starts.all(axis=0)).any()
+        assert gate.sum() < len(tasks)
+    elif case == "every_step_a_run":
+        assert starts[:, :len(tasks)].all()
+    else:
+        assert xs["valid"].shape[1] == 64 and gate.sum() < len(tasks)
+
+
 # ---------------------------------------------------------------------------
 # hand-off paths (fused scan can't express the window -> soa, which is
 # parity-locked already)
@@ -241,18 +294,22 @@ def test_window_stats_count_device_and_soa_windows():
     """A clustered window is handed to soa and counted as such; a plain
     window is placed by the scan and counted as a device window, with the
     arrays it moved: 11 task streams, 18 carry seeds, 12 constant tables
-    and 12 scalars up; the 18 carry registers and 3 outputs back."""
+    and 12 scalars up; the 18 carry registers and 3 outputs back.  Its 28
+    tasks, 4 of each of 7 functions, are 28 scan steps, 7 of which start a
+    run (runs are whole functions under every heuristic)."""
     tasks, eps, store, tm = _setup(n_per=4)
     pops.reset_window_stats()
     cluster_mhra(tasks, eps, store, tm, alpha=0.5, max_cluster_size=16,
                  engine="jax")
-    zero = {"h2d_bytes": 0, "h2d_arrays": 0, "d2h_bytes": 0, "d2h_arrays": 0}
+    zero = {"h2d_bytes": 0, "h2d_arrays": 0, "d2h_bytes": 0, "d2h_arrays": 0,
+            "basis_steps": 0, "scan_steps": 0}
     assert pops.WINDOW_STATS == {"device": 0, "soa": 1, **zero}
     mhra(tasks, eps, store, tm, alpha=0.5, engine="jax")
     one = dict(pops.WINDOW_STATS)
     assert (one["device"], one["soa"]) == (1, 1)
     assert (one["h2d_arrays"], one["d2h_arrays"]) == (53, 21)
     assert one["h2d_bytes"] > 0 and one["d2h_bytes"] > 0
+    assert (one["basis_steps"], one["scan_steps"]) == (7, 28)
     mhra(tasks, eps, store, tm, alpha=0.5, engine="soa")
     assert pops.WINDOW_STATS == one
     pops.reset_window_stats()

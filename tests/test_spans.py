@@ -6,6 +6,7 @@ import glob
 import os
 
 import jax
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.profiler import ProfileData
@@ -83,13 +84,14 @@ def test_flush_spans_say_what_closed_the_window_and_how_long_it_waited(tmp_path)
 def test_a_device_window_counts_the_bytes_of_the_arrays_it_moves(tmp_path,
                                                                  monkeypatch):
     eps, store = _fleet()
-    moved = []
+    moved, moved_xs = [], []
     scan = ops._greedy_scan
 
     def spy(consts, init, xs, **kw):
         out = scan(consts, init, xs, **kw)
         moved.append((jax.tree_util.tree_leaves((consts, init, xs)),
                       jax.tree_util.tree_leaves(out)))
+        moved_xs.append(xs)
         return out
 
     monkeypatch.setattr(ops, "_greedy_scan", spy)
@@ -99,12 +101,21 @@ def test_a_device_window_counts_the_bytes_of_the_arrays_it_moves(tmp_path,
     (up, down), = moved
     h2d, = [ev[3] for ev in evs if ev[0] == "gf.h2d"]
     d2h, = [ev[3] for ev in evs if ev[0] == "gf.d2h"]
+    scan, = [ev[3] for ev in evs if ev[0] == "gf.scan"]
     assert len(up) == 53
     assert h2d == {"bytes": sum(a.nbytes for a in up), "arrays": 53}
     assert d2h == {"bytes": sum(a.nbytes for a in down), "arrays": 21}
+    # 24 tasks, 24 steps; the pass runs where some heuristic starts a run
+    xs = moved_xs[0]
+    valid = np.asarray(xs["valid"])
+    steps = {"basis_steps": int((np.asarray(xs["new_run"]) & valid).any(0).sum()),
+             "scan_steps": int(valid.any(0).sum())}
+    assert steps["scan_steps"] == 24 and 1 <= steps["basis_steps"] <= 24
+    assert scan == steps
     assert ops.WINDOW_STATS == {"device": 1, "soa": 0,
                                 "h2d_bytes": h2d["bytes"], "h2d_arrays": 53,
-                                "d2h_bytes": d2h["bytes"], "d2h_arrays": 21}
+                                "d2h_bytes": d2h["bytes"], "d2h_arrays": 21,
+                                **steps}
     # outside an engine no window id; the commit reports the live timeline
     commit, = [ev[3] for ev in evs if ev[0] == "gf.commit"]
     assert commit == {"timeline_len": 24}
