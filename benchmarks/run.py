@@ -43,7 +43,6 @@ def main() -> None:
         placement_latency,
         placement_strategies,
         profile_tasks,
-        roofline,
         scheduler_overhead,
     )
 
@@ -91,7 +90,6 @@ def main() -> None:
         "alpha_sweep": lambda: alpha_sweep.main() if not args.quick else _alpha(n_alpha),
         "molecular_design": lambda: molecular_design.main(),
         "paper_eval": _paper_eval,
-        "roofline": lambda: roofline.main(),
     }
 
     def _alpha(n):

@@ -2,9 +2,7 @@
 
 On the CPU testbed the counter vector mirrors the paper's perfmon set:
     [LLC_MISSES, INSTRUCTIONS_RETIRED, CPU_CYCLES, REF_CYCLES]
-On TPU endpoints the analogous dynamic-power features are HLO-derived:
-    [FLOPs_executed, HBM_bytes, ICI_bytes, duty_cycle]
-Both are just per-process/per-job vectors X fed to the linear power model.
+It is a per-process/per-job vector X fed to the linear power model.
 """
 from __future__ import annotations
 
@@ -14,7 +12,6 @@ from typing import Sequence
 import numpy as np
 
 CPU_COUNTERS = ("LLC_MISSES", "INSTRUCTIONS_RETIRED", "CPU_CYCLES", "REF_CYCLES")
-TPU_COUNTERS = ("FLOPS", "HBM_BYTES", "ICI_BYTES", "DUTY")
 
 
 @dataclasses.dataclass
@@ -56,7 +53,7 @@ class TaskRecord:
 def counter_width(samples: Sequence[CounterSample]) -> int:
     """Length of the counter vectors carried by ``samples`` (0 if no
     process was ever observed).  The CPU testbed uses 4-wide perfmon
-    vectors, but TPU/extended counter sets may differ — callers must not
+    vectors, but other counter sets may differ — callers must not
     assume a width."""
     for s in samples:
         for v in s.procs.values():

@@ -1,7 +1,7 @@
-"""Endpoints: the machines/pods GreenFaaS schedules onto.
+"""Endpoints: the machines GreenFaaS schedules onto.
 
-Covers both the paper's Table-I testbed (CPU machines behind Globus
-Compute endpoints) and TPU pod/slice endpoints for the fleet integration.
+The paper's Table-I testbed (CPU machines behind Globus Compute
+endpoints) and its replicated, heterogeneous scale-out fleets.
 """
 from __future__ import annotations
 
@@ -12,7 +12,7 @@ from typing import Mapping
 @dataclasses.dataclass(frozen=True)
 class EndpointSpec:
     name: str
-    cores: int                       # concurrent task slots (workers / pods)
+    cores: int                       # concurrent task slots (workers)
     idle_power_w: float              # node idle draw while allocated
     tdp_w: float                     # max sustained draw
     queue_delay_s: float             # batch-scheduler queue time (0 = always on)
@@ -23,11 +23,6 @@ class EndpointSpec:
     cold_start_s: float = 0.0        # latency of spinning up a cold worker
     cold_start_j: float = 0.0        # startup energy of a cold worker
     keepalive_s: float = float("inf")  # idle gap after which a worker goes cold
-    # --- TPU-fleet extras (unused by the CPU testbed) ---
-    chips: int = 0
-    peak_flops: float = 0.0          # per chip, FLOP/s (bf16)
-    hbm_bw: float = 0.0              # per chip, B/s
-    ici_bw: float = 0.0              # per link, B/s
 
     @property
     def always_on(self) -> bool:
@@ -113,40 +108,3 @@ def scaled_testbed(replicas: int) -> list[EndpointSpec]:
             ))
     return eps
 
-
-# ---------------------------------------------------------------------------
-# TPU fleet endpoints (v5e constants per brief; power figures are config)
-# ---------------------------------------------------------------------------
-
-V5E_PEAK_FLOPS = 197e12
-V5E_HBM_BW = 819e9
-V5E_ICI_BW = 50e9
-V5E_IDLE_W = 80.0
-V5E_PEAK_W = 250.0
-
-
-def tpu_fleet(pods: int = 2, chips_per_pod: int = 256) -> list[EndpointSpec]:
-    """A heterogeneous fleet: big pods + an always-on small slice (the
-    'desktop' analogue) + an older-generation pod (the 'theta' analogue)."""
-    eps = []
-    for i in range(pods):
-        eps.append(EndpointSpec(
-            f"pod{i}", cores=chips_per_pod, idle_power_w=V5E_IDLE_W * chips_per_pod,
-            tdp_w=V5E_PEAK_W * chips_per_pod, queue_delay_s=120.0,
-            chips=chips_per_pod, peak_flops=V5E_PEAK_FLOPS,
-            hbm_bw=V5E_HBM_BW, ici_bw=V5E_ICI_BW,
-            hops={f"pod{j}": 4 for j in range(pods) if j != i} | {"slice0": 6, "oldpod": 8},
-        ))
-    eps.append(EndpointSpec(
-        "slice0", cores=16, idle_power_w=V5E_IDLE_W * 16,
-        tdp_w=V5E_PEAK_W * 16, queue_delay_s=0.0, has_batch_scheduler=False,
-        chips=16, peak_flops=V5E_PEAK_FLOPS, hbm_bw=V5E_HBM_BW, ici_bw=V5E_ICI_BW,
-        hops={f"pod{j}": 6 for j in range(pods)} | {"oldpod": 8},
-    ))
-    eps.append(EndpointSpec(
-        "oldpod", cores=128, idle_power_w=100.0 * 128, tdp_w=320.0 * 128,
-        queue_delay_s=300.0, chips=128, peak_flops=123e12, hbm_bw=409e9,
-        ici_bw=25e9, perf_scale=0.6,
-        hops={f"pod{j}": 8 for j in range(pods)} | {"slice0": 8},
-    ))
-    return eps

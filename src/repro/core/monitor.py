@@ -2,9 +2,8 @@
 
 The paper stacks per-device monitors (RAPL CPU, Cray HSS, NVML GPU) into a
 node monitor.  The abstraction is identical here; concrete sources are the
-testbed simulator (CPU container has no power rails) and the TPU-counter
-model.  Monitors return instantaneous watts; the attribution pipeline
-integrates.
+testbed simulator (CPU container has no power rails).  Monitors return
+instantaneous watts; the attribution pipeline integrates.
 """
 from __future__ import annotations
 
@@ -68,21 +67,3 @@ class ConstantMonitor(EnergyMonitor):
 
     def read_watts(self, t: float) -> float:
         return self.watts
-
-
-class TPUCounterMonitor(EnergyMonitor):
-    """TPU-fleet power source: maps utilization-counter rates to watts via
-    a device coefficient model (the simulator's 'ground truth'; the GreenFaaS
-    pipeline re-learns its own linear fit from the stream, same as RAPL)."""
-
-    name = "tpu"
-
-    def __init__(self, idle_w: float, peak_w: float, util_fn):
-        self.idle_w = idle_w
-        self.peak_w = peak_w
-        self.util_fn = util_fn  # t -> (flops_frac, hbm_frac, ici_frac)
-
-    def read_watts(self, t: float) -> float:
-        f, h, i = self.util_fn(t)
-        dyn = self.peak_w - self.idle_w
-        return self.idle_w + dyn * min(0.6 * f + 0.3 * h + 0.1 * i, 1.0)

@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# The device side of MHRA placement: ``placement/`` holds the fused
+# ``lax.scan`` greedy (ops.py), its NumPy oracle (ref.py) and a Pallas
+# score kernel run only in interpret mode (kernel.py); ``dispatch.py``
+# selects the placement backend.

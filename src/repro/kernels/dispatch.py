@@ -1,36 +1,11 @@
-"""Kernel backend selection.
-
-"pallas"            — real TPU lowering (target hardware)
-"pallas_interpret"  — kernel body emulated on CPU (tests)
-"xla"               — chunked pure-jnp path (CPU dry-run / fallback)
-
-Default: pallas on TPU, xla elsewhere; override with REPRO_KERNEL_BACKEND.
-The placement pass has its own selection (``placement_backend``).
+"""Backend selection for the placement score+argmin pass
+(``placement_backend``): the fused jnp scan (``xla``), the Pallas kernel
+emulated on the host (``pallas_interpret``) or the NumPy reference
+(``ref``), chosen by REPRO_PLACEMENT_BACKEND.
 """
 from __future__ import annotations
 
 import os
-
-import jax
-
-_VALID = ("pallas", "pallas_interpret", "xla")
-
-
-def backend() -> str:
-    env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env:
-        assert env in _VALID, env
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
-
-
-def use_pallas() -> bool:
-    return backend() in ("pallas", "pallas_interpret")
-
-
-def interpret() -> bool:
-    return backend() == "pallas_interpret"
-
 
 _P_VALID = ("pallas", "pallas_interpret", "xla", "ref")
 

@@ -462,8 +462,8 @@ def greedy_window(n_ep: int, consts: dict, init: dict, xs: dict):
     scan_steps = int(valid.any(axis=0).sum())
     basis_steps = int((np.asarray(xs["new_run"]) & valid).any(axis=0).sum())
     # x64 is scoped to the placement scan (trace + execute) rather than
-    # enabled process-wide: the parity contract is float64, but sibling
-    # kernels in this package trace float32 and must stay untouched
+    # enabled process-wide: the float64 parity contract is the scan's own,
+    # and the caller's other JAX code keeps its default precision
     with seen, jax.enable_x64(True):
         with span("h2d", bytes=h2d_bytes, arrays=len(host)):
             jxs = jax.tree_util.tree_map(jnp.asarray, xs)

@@ -1,7 +1,6 @@
 import os
 
-# Tests see the single real CPU device — the 512-device override is ONLY for
-# the dry-run launcher (see src/repro/launch/dryrun.py).
+# Tests see the single real CPU device.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np

@@ -79,16 +79,22 @@ def test_jax_matches_soa_scaled_fleet():
     _assert_bitwise(a, b)
 
 
-def test_jax_matches_soa_all_registers():
-    """carbon + fairness + warm + alive + lookahead + not_before, armed
-    together: the interaction of registers is what historically breaks
-    mirrored float sequences."""
-    tasks, eps, store, tm = _setup(n_per=6)
-    n_ep = len(eps)
+REGISTERS = ("carbon", "fairness", "warm", "alive", "lookahead",
+             "not_before")
+
+
+def _registers(tasks, n_ep, armed):
+    """The scoring registers ``armed`` names (or every one, ``"all"``) over
+    ``tasks`` on an ``n_ep`` fleet: the tasks, with ``not_before`` floors
+    when that register is armed, and the ``mhra`` keywords of the others.
+    Every register's values are drawn in one order whatever is armed, so a
+    register alone scores exactly as it does among the rest."""
     rng = np.random.default_rng(0)
+    on = set(REGISTERS) if armed == "all" else {armed}
+    floors = [float(rng.uniform(0.0, 20.0)) for _ in tasks]
     tasks = [
         TaskSpec(id=t.id, fn=t.fn, inputs=t.inputs,
-                 not_before=float(rng.uniform(0.0, 20.0)),
+                 not_before=floors[i] if "not_before" in on else 0.0,
                  user=("alice", "bob")[i % 2])
         for i, t in enumerate(tasks)
     ]
@@ -110,9 +116,45 @@ def test_jax_matches_soa_all_registers():
     )
     kw = dict(carbon=carbon, fairness=fairness, warm=warm, alive=alive,
               lookahead=lw)
-    a = mhra(tasks, eps, store, tm, alpha=0.4, engine="soa", **kw)
-    b = mhra(tasks, eps, store, tm, alpha=0.4, engine="jax", **kw)
+    return tasks, {k: v for k, v in kw.items() if k in on}
+
+
+@pytest.mark.parametrize("alpha", [0.0, 0.4, 1.0])
+@pytest.mark.parametrize("replicas", [1, 3], ids=["table1", "table1x3"])
+@pytest.mark.parametrize("register", REGISTERS + ("all",))
+def test_jax_matches_soa_registers(register, replicas, alpha):
+    """Each scoring register alone, then carbon + fairness + warm + alive +
+    lookahead + not_before armed together (the interaction of registers
+    is what historically breaks mirrored float sequences), on the 4- and
+    12-endpoint fleets, at both ends of the objective and between."""
+    tasks, eps, store, tm = _setup(n_per=6, replicas=replicas)
+    tasks, kw = _registers(tasks, len(eps), register)
+    a = mhra(tasks, eps, store, tm, alpha=alpha, engine="soa", **kw)
+    b = mhra(tasks, eps, store, tm, alpha=alpha, engine="jax", **kw)
     _assert_bitwise(a, b)
+
+
+@pytest.mark.parametrize("n_tasks", [1, 2, 31, 32, 63, 64, 65])
+def test_jax_matches_soa_window_sizes(n_tasks, monkeypatch):
+    """Windows at and beside the power-of-two pad of the scan's step axis:
+    the pad steps must carry every register through unchanged, bitwise."""
+    tasks, eps, store, tm = _setup(n_per=10, replicas=3)
+    tasks = tasks[:n_tasks]
+    seen = []
+    call = pops.greedy_window
+
+    def spy(n_ep, consts, init, xs):
+        seen.append(np.array(xs["valid"]))
+        return call(n_ep, consts, init, xs)
+
+    monkeypatch.setattr(pops, "greedy_window", spy)
+    pops.reset_window_stats()
+    a = mhra(tasks, eps, store, tm, alpha=0.4, engine="soa")
+    b = mhra(tasks, eps, store, tm, alpha=0.4, engine="jax")
+    _assert_bitwise(a, b)
+    valid, = seen
+    assert valid.shape[1] == pops.bucket_pow2(n_tasks)
+    assert pops.WINDOW_STATS["scan_steps"] == n_tasks
 
 
 def _gate_case(case, tasks):
@@ -205,13 +247,13 @@ def test_jax_empty_window():
 # ---------------------------------------------------------------------------
 
 
-def _online(engine):
+def _online(engine, alpha, sizes):
     eps = table1_testbed()
     sim = TestbedSim(eps, seed=0)
-    eng = OnlineEngine(eps, sim, policy="mhra", alpha=0.2, monitoring=False,
+    eng = OnlineEngine(eps, sim, policy="mhra", alpha=alpha, monitoring=False,
                        window_s=30.0, max_batch=10**6, engine=engine)
     out = []
-    for w, n in enumerate((70, 3, 41)):   # deep, tiny, medium windows
+    for w, n in enumerate(sizes):
         eng.submit_many([
             TaskSpec(id=f"w{w}t{i}", fn=SEBS_FUNCTIONS[i % 7])
             for i in range(n)
@@ -222,9 +264,14 @@ def _online(engine):
     return out, eng
 
 
-def test_online_jax_state_matches_soa_state():
-    a, eng_a = _online("soa")
-    b, eng_b = _online("jax")
+@pytest.mark.parametrize("sizes", [(70, 3, 41), (1, 1, 1), (200, 64, 129)],
+                         ids=["deep-tiny-medium", "single-tasks", "padded"])
+@pytest.mark.parametrize("alpha", [0.0, 0.2, 0.5, 1.0])
+def test_online_jax_state_matches_soa_state(alpha, sizes):
+    """The live state carried across windows of the given sizes stays
+    soa's, double for double, at every objective weighting."""
+    a, eng_a = _online("soa", alpha, sizes)
+    b, eng_b = _online("jax", alpha, sizes)
     assert isinstance(eng_a.state, SoAState)
     assert isinstance(eng_b.state, SoAState)
     for (asg_a, e_a, c_a), (asg_b, e_b, c_b) in zip(a, b):
@@ -249,13 +296,28 @@ def test_online_engine_param_builds_jax_policy():
 # ---------------------------------------------------------------------------
 
 
-def test_auto_engine_jax_tier():
-    me, mc = sched.AUTO_JAX_MIN_ENDPOINTS, sched.AUTO_JAX_MIN_CELLS
-    assert auto_engine(me, mc // me) == "jax"
-    assert auto_engine(me, mc // me - 1) == "soa"          # cells short
-    assert auto_engine(me - 1, 10 ** 9) == "soa"           # fleet short
-    # streaming mode (window size unknown) never escalates to jax
-    assert auto_engine(10 ** 4) == "soa"
+@pytest.mark.parametrize("n_endpoints, n_tasks, engine", [
+    (8, 8192, "jax"),        # the jax tier's corner: 8 x 8192 = 2^16 cells
+    (8, 8191, "soa"),        # one task short
+    (7, 10 ** 9, "soa"),     # one endpoint short
+    (10 ** 4, None, "soa"),  # streaming never escalates to jax
+    (32, 8192, "jax"),       # the batch cell's window
+    (256, 256, "jax"),       # a full stream window
+    (256, 255, "soa"),       # a timer-closed stream window
+    (16, None, "soa"),
+    (15, None, "delta"),
+    (4, None, "delta"),      # the paper's Table-I testbed, streaming
+    (4, 64, "soa"),          # 256 cells
+    (4, 63, "delta"),
+    (8, 32, "soa"),
+    (8, 31, "delta"),
+    (16, 1, "soa"),          # wide enough for soa at any depth
+    (15, 17, "delta"),
+    (15, 18, "soa"),
+])
+def test_auto_engine_resolves(n_endpoints, n_tasks, engine):
+    """Every edge of the fleet-size / window-depth crossover."""
+    assert auto_engine(n_endpoints, n_tasks) == engine
 
 
 def _hide_placement_ops(monkeypatch):
@@ -321,7 +383,7 @@ def test_auto_batch_escalates_to_jax_and_matches_soa(monkeypatch):
     stays bitwise-identical to an explicit soa run.  The calibrated
     thresholds need thousands of tasks, so drop them to the fixture size
     — the routing logic is what's under test, the calibration is pinned
-    by test_auto_engine_jax_tier."""
+    by test_auto_engine_resolves."""
     tasks, eps, store, tm = _setup(n_per=3, with_inputs=False, replicas=2)
     monkeypatch.setattr(sched, "AUTO_JAX_MIN_ENDPOINTS", len(eps))
     monkeypatch.setattr(sched, "AUTO_JAX_MIN_CELLS", len(eps) * len(tasks))
@@ -339,9 +401,7 @@ def test_auto_batch_escalates_to_jax_and_matches_soa(monkeypatch):
 def test_placement_backend_env_override(monkeypatch):
     from repro.kernels import dispatch
     monkeypatch.delenv("REPRO_PLACEMENT_BACKEND", raising=False)
-    # the fused jnp scan everywhere; it never inherits the generic
-    # kernel backend's "pallas"
-    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    # the fused jnp scan everywhere
     assert dispatch.placement_backend() == "xla"
     assert not dispatch.placement_use_pallas()
     assert pops.lane_bucket(32) == 32

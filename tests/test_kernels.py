@@ -1,219 +1,8 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
-+ hypothesis property tests on the flash-attention invariants."""
-import jax
-import jax.numpy as jnp
+"""The placement score+argmin pass (the ``engine="jax"`` building blocks):
+the NumPy oracle, the fused jnp pass and the Pallas kernel in interpret
+mode, against each other and against NumPy's own reductions."""
 import numpy as np
 import pytest
-
-from _hypothesis_compat import given, settings, st
-
-from repro.kernels.decode_attention.kernel import decode_attention
-from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.kernels.flash_attention.kernel import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.selective_scan.kernel import selective_scan
-from repro.kernels.selective_scan.ref import selective_scan_ref
-from repro.kernels.ssd.kernel import ssd
-from repro.kernels.ssd.ref import ssd_preweighted_ref, ssd_ref
-
-RNG = jax.random.PRNGKey(0)
-
-
-def _tol(dtype):
-    return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 else dict(atol=2e-5, rtol=2e-5)
-
-
-# --------------------------------------------------------------------------
-# flash attention
-# --------------------------------------------------------------------------
-FLASH_CASES = [
-    # (b, sq, sk, h, kv, d, causal, dtype)
-    (2, 128, 128, 4, 2, 64, True, jnp.float32),
-    (1, 256, 256, 8, 8, 128, True, jnp.float32),
-    (2, 128, 256, 2, 1, 64, False, jnp.float32),
-    (1, 128, 128, 4, 4, 128, True, jnp.bfloat16),
-    (1, 384, 384, 6, 6, 64, True, jnp.float32),   # whisper-like MHA
-    (2, 128, 128, 4, 1, 80, True, jnp.float32),   # zamba-like head_dim 80
-]
-
-
-@pytest.mark.parametrize("case", FLASH_CASES)
-def test_flash_attention_matches_ref(case):
-    b, sq, sk, h, kv, d, causal, dtype = case
-    ks = jax.random.split(RNG, 3)
-    q = jax.random.normal(ks[0], (b, sq, h, d), dtype)
-    k = jax.random.normal(ks[1], (b, sk, kv, d), dtype)
-    v = jax.random.normal(ks[2], (b, sk, kv, d), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64, interpret=True)
-    ref = attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype)
-    )
-
-
-@settings(max_examples=10, deadline=None)
-@given(
-    bq=st.sampled_from([32, 64, 128]),
-    bk=st.sampled_from([32, 64]),
-    mult=st.integers(1, 3),
-    h=st.sampled_from([2, 4]),
-    causal=st.booleans(),
-)
-def test_flash_attention_block_invariance(bq, bk, mult, h, causal):
-    """Output must not depend on block decomposition (property)."""
-    sq = bq * mult
-    sk = max(128, sq)  # causal sq > sk leaves fully-masked rows (undefined)
-    ks = jax.random.split(jax.random.PRNGKey(bq * 7 + bk), 3)
-    q = jax.random.normal(ks[0], (1, sq, h, 64), jnp.float32)
-    k = jax.random.normal(ks[1], (1, sk, h, 64), jnp.float32)
-    v = jax.random.normal(ks[2], (1, sk, h, 64), jnp.float32)
-    a = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=min(bk, sk), interpret=True)
-    b_ = attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=3e-5, rtol=3e-5)
-
-
-# --------------------------------------------------------------------------
-# decode attention
-# --------------------------------------------------------------------------
-DECODE_CASES = [
-    (2, 256, 4, 2, 64, 64, jnp.float32),
-    (1, 512, 8, 1, 128, 128, jnp.float32),
-    (3, 128, 4, 4, 64, 64, jnp.bfloat16),
-    (1, 256, 8, 8, 80, 128, jnp.float32),
-]
-
-
-@pytest.mark.parametrize("case", DECODE_CASES)
-def test_decode_attention_matches_ref(case):
-    b, S, h, kv, d, bk, dtype = case
-    ks = jax.random.split(RNG, 3)
-    q = jax.random.normal(ks[0], (b, 1, h, d), dtype)
-    kc = jax.random.normal(ks[1], (b, S, kv, d), dtype)
-    vc = jax.random.normal(ks[2], (b, S, kv, d), dtype)
-    lens = jnp.arange(1, b + 1) * (S // (b + 1)) + 3
-    out = decode_attention(q, kc, vc, lens.astype(jnp.int32), block_k=bk, interpret=True)
-    ref = decode_attention_ref(q, kc, vc, lens)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype)
-    )
-
-
-def test_decode_attention_ignores_stale_cache_tail():
-    """Garbage past cache_len must not affect the result (masking property)."""
-    b, S, h, d = 1, 128, 2, 64
-    ks = jax.random.split(RNG, 3)
-    q = jax.random.normal(ks[0], (b, 1, h, d))
-    kc = jax.random.normal(ks[1], (b, S, h, d))
-    vc = jax.random.normal(ks[2], (b, S, h, d))
-    lens = jnp.array([40], jnp.int32)
-    a = decode_attention(q, kc, vc, lens, block_k=32, interpret=True)
-    kc2 = kc.at[:, 40:].set(1e4)
-    vc2 = vc.at[:, 40:].set(-1e4)
-    b_ = decode_attention(q, kc2, vc2, lens, block_k=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-6)
-
-
-# --------------------------------------------------------------------------
-# selective scan (mamba1)
-# --------------------------------------------------------------------------
-SCAN_CASES = [
-    (2, 64, 128, 16, 64, 32, jnp.float32),
-    (1, 128, 64, 8, 64, 64, jnp.float32),
-    (1, 64, 256, 16, 128, 32, jnp.float32),
-]
-
-
-@pytest.mark.parametrize("case", SCAN_CASES)
-def test_selective_scan_matches_ref(case):
-    b, L, d, n, bd, ch, dtype = case
-    ks = jax.random.split(RNG, 6)
-    x = jax.random.normal(ks[0], (b, L, d), dtype)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, L, d)) * 0.5 - 1)
-    A = -jnp.exp(jax.random.normal(ks[2], (d, n)) * 0.3)
-    B = jax.random.normal(ks[3], (b, L, n))
-    C = jax.random.normal(ks[4], (b, L, n))
-    D = jax.random.normal(ks[5], (d,))
-    out = selective_scan(x, dt, A, B, C, D, block_d=bd, chunk=ch, interpret=True)
-    ref = selective_scan_ref(x, dt, A, B, C, D)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
-
-
-def test_selective_scan_chunk_invariance():
-    """Chunk size must not change the result (state carry property)."""
-    b, L, d, n = 1, 128, 64, 8
-    ks = jax.random.split(RNG, 6)
-    x = jax.random.normal(ks[0], (b, L, d))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, L, d)) * 0.3)
-    A = -jnp.exp(jax.random.normal(ks[2], (d, n)) * 0.3)
-    B = jax.random.normal(ks[3], (b, L, n))
-    C = jax.random.normal(ks[4], (b, L, n))
-    D = jnp.zeros((d,))
-    o32 = selective_scan(x, dt, A, B, C, D, block_d=64, chunk=32, interpret=True)
-    o128 = selective_scan(x, dt, A, B, C, D, block_d=64, chunk=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(o32), np.asarray(o128), atol=1e-5)
-
-
-# --------------------------------------------------------------------------
-# ssd (mamba2)
-# --------------------------------------------------------------------------
-SSD_CASES = [
-    (2, 64, 4, 64, 32, 32, jnp.float32),
-    (1, 128, 2, 64, 64, 64, jnp.float32),
-    (1, 128, 8, 128, 64, 32, jnp.float32),
-]
-
-
-@pytest.mark.parametrize("case", SSD_CASES)
-def test_ssd_matches_ref(case):
-    b, L, nh, hd, n, ch, dtype = case
-    ks = jax.random.split(RNG, 5)
-    xh = jax.random.normal(ks[0], (b, L, nh, hd), dtype)
-    dt = jax.random.normal(ks[1], (b, L, nh)) * 0.5
-    A_log = jax.random.normal(ks[2], (nh,)) * 0.3
-    B = jax.random.normal(ks[3], (b, L, n))
-    C = jax.random.normal(ks[4], (b, L, n))
-    dtf = jax.nn.softplus(dt)
-    A = -jnp.exp(A_log)
-    y, S = ssd(xh * dtf[..., None], dtf * A, B, C, chunk=ch, interpret=True)
-    yr, Sr = ssd_ref(xh, dt, A_log, B, C)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=5e-4, rtol=5e-3)
-    np.testing.assert_allclose(np.asarray(S), np.asarray(Sr), atol=5e-4, rtol=5e-3)
-
-
-def test_ssd_xla_chunked_matches_sequential():
-    """models/ssm.ssd_chunked (the XLA path) vs the sequential oracle."""
-    from repro.models.ssm import ssd_chunked
-
-    b, L, nh, hd, n = 2, 96, 4, 32, 16
-    ks = jax.random.split(RNG, 5)
-    xh = jax.random.normal(ks[0], (b, L, nh, hd))
-    dt = jax.random.normal(ks[1], (b, L, nh)) * 0.5
-    A_log = jax.random.normal(ks[2], (nh,)) * 0.3
-    B = jax.random.normal(ks[3], (b, L, n))
-    C = jax.random.normal(ks[4], (b, L, n))
-    y, S = ssd_chunked(xh, dt, A_log, B, C, chunk=32)
-    yr, Sr = ssd_ref(xh, dt, A_log, B, C)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=5e-4, rtol=5e-3)
-    np.testing.assert_allclose(np.asarray(S), np.asarray(Sr), atol=5e-4, rtol=5e-3)
-
-
-def test_preweighted_ref_consistent():
-    b, L, nh, hd, n = 1, 32, 2, 16, 8
-    ks = jax.random.split(RNG, 5)
-    xh = jax.random.normal(ks[0], (b, L, nh, hd))
-    dt = jax.random.normal(ks[1], (b, L, nh)) * 0.5
-    A_log = jax.random.normal(ks[2], (nh,)) * 0.3
-    B = jax.random.normal(ks[3], (b, L, n))
-    C = jax.random.normal(ks[4], (b, L, n))
-    dtf = jax.nn.softplus(dt)
-    y1, S1 = ssd_preweighted_ref(xh * dtf[..., None], dtf * -jnp.exp(A_log), B, C)
-    y2, S2 = ssd_ref(xh, dt, A_log, B, C)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# placement score+argmin (the engine="jax" building blocks)
-# ---------------------------------------------------------------------------
 
 
 def _placement_case(seed, n):
@@ -237,7 +26,11 @@ def _placement_case(seed, n):
     return kw
 
 
-@pytest.mark.parametrize("seed,n", [(0, 4), (1, 12), (2, 128), (3, 200)])
+@pytest.mark.parametrize("seed,n", [
+    (0, 4), (1, 12), (2, 128), (3, 200),
+    # either side of the 128-lane tile and of the power-of-two buckets
+    (4, 1), (5, 127), (6, 129), (7, 256), (8, 257),
+])
 def test_placement_score_backends_bitwise(seed, n, monkeypatch):
     """ref (NumPy oracle) and xla produce bitwise-equal objectives and the
     identical first-min argmin.  The pallas-interpret leg is compiled as

@@ -1,10 +1,9 @@
 """End-to-end system tests: the full GreenFaaS pipeline on the simulated
-Table-I testbed, fleet fault tolerance, and the energy report."""
-import numpy as np
+Table-I testbed and the energy report."""
 import pytest
 
 from repro.core.database import TaskDB
-from repro.core.endpoint import table1_testbed, tpu_fleet
+from repro.core.endpoint import table1_testbed
 from repro.core.executor import GreenFaaSExecutor
 from repro.core.report import html_report, text_report
 from repro.core.scheduler import TaskSpec
@@ -88,73 +87,3 @@ def test_db_roundtrip(tmp_path):
     assert len(db2.records) == len(ex.db.records)
     assert db2.energy_by_endpoint().keys() == ex.db.energy_by_endpoint().keys()
 
-
-# ---------------------------------------------------------------------------
-# fleet fault tolerance
-# ---------------------------------------------------------------------------
-
-
-def _fleet_mgr(tmp_path):
-    import json
-
-    from repro.fleet.manager import FleetManager
-
-    d = tmp_path / "dryrun"
-    d.mkdir()
-    (d / "a__train_4k__single.json").write_text(json.dumps({
-        "arch": "granite-3-2b", "shape": "train_4k", "n_devices": 256,
-        "extrapolated": {"flops_extrap": 1e14, "bytes_extrap": 1e12,
-                         "coll_bytes_extrap": 1e10},
-    }))
-    return FleetManager(tpu_fleet(), d)
-
-
-def test_fleet_placement_and_heartbeats(tmp_path):
-    from repro.fleet.manager import FleetJob, HEARTBEAT_TIMEOUT_S
-
-    mgr = _fleet_mgr(tmp_path)
-    jobs = [FleetJob(id=f"j{i}", arch="granite-3-2b", shape="train_4k") for i in range(6)]
-    s = mgr.place(jobs)
-    assert set(s.assignments) == {j.id for j in jobs}
-    # endpoint misses heartbeats -> marked down -> placement avoids it
-    t0 = 1000.0
-    for name in mgr.endpoints:
-        mgr.heartbeat(name, now=t0)
-    mgr.heartbeat("pod0", now=t0)  # pod0 then goes silent
-    for name in mgr.endpoints:
-        if name != "pod0":
-            mgr.heartbeat(name, now=t0 + HEARTBEAT_TIMEOUT_S + 5)
-    down = mgr.check_health(now=t0 + HEARTBEAT_TIMEOUT_S + 5)
-    assert down == ["pod0"]
-    s2 = mgr.place(jobs)
-    assert "pod0" not in set(s2.assignments.values())
-
-
-def test_fleet_straggler_detection(tmp_path):
-    from repro.fleet.manager import FleetJob
-
-    mgr = _fleet_mgr(tmp_path)
-    job = FleetJob(id="j0", arch="granite-3-2b", shape="train_4k")
-    rng = np.random.default_rng(0)
-    for _ in range(10):
-        mgr.observe_step(job, "pod0", seconds=1.0 + rng.normal(0, 0.01), energy_j=100.0)
-    assert not mgr.observe_step(job, "pod0", seconds=1.01, energy_j=100.0)
-    assert mgr.observe_step(job, "pod0", seconds=5.0, energy_j=100.0)  # 3sigma+
-    assert any("straggler" in e for e in mgr.events)
-
-
-def test_fleet_elastic_join_leave(tmp_path):
-    from repro.core.endpoint import EndpointSpec
-    from repro.fleet.manager import FleetJob
-
-    mgr = _fleet_mgr(tmp_path)
-    jobs = [FleetJob(id=f"j{i}", arch="granite-3-2b", shape="train_4k") for i in range(4)]
-    mgr.endpoint_leave("pod1")
-    s = mgr.place(jobs)
-    assert "pod1" not in set(s.assignments.values())
-    mgr.endpoint_join(EndpointSpec(
-        "pod9", cores=512, idle_power_w=80 * 512, tdp_w=250 * 512,
-        queue_delay_s=60.0, chips=512, peak_flops=197e12, hbm_bw=819e9,
-        ici_bw=50e9,
-    ))
-    assert "pod9" in {e.name for e in mgr.live_endpoints()}
