@@ -375,9 +375,14 @@ def _greedy_scan(consts, init, xs, *, n_ep, use_kernel):
         lk_c1 = lam_b1 * x["u_tw"]
         lk_c2 = lam_a1 * x["u_oj"]
         lk_e = e2 * lk_c1 + hv_tab[x["hv_id"], ei] * lk_c2
-        # fw_r (row 4) is per-run, never refreshed by a commit
-        run_regs2 = run_regs.at[:4, ei].set(
-            sel(jnp.stack([e_b, nl2, g_b, lk_e]), run_regs[:4, ei])
+        # the run registers' column is read and written whole, like
+        # ``bcol``: under the vmap a gather or scatter of part of a column
+        # (``[:4, ei]``) has no fusion on XLA:TPU, which lowers it to a
+        # serial loop over heuristics inside every step.  fw_r (row 4) is
+        # per-run, never refreshed by a commit: it is written back as read
+        rcol = run_regs[:, ei]
+        run_regs2 = run_regs.at[:, ei].set(
+            sel(jnp.stack([e_b, nl2, g_b, lk_e, rcol[4]]), rcol)
         )
         c_cur2 = sel(jnp.maximum(c_cur, end_v), c_cur)
 

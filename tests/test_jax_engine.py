@@ -134,6 +134,25 @@ def test_jax_matches_soa_registers(register, replicas, alpha):
     _assert_bitwise(a, b)
 
 
+@pytest.mark.parametrize("mu", [0.6, 1.0])
+@pytest.mark.parametrize("alpha", [0.4, 0.5])
+def test_jax_matches_soa_fairness_debts_across_runs(alpha, mu):
+    """Debts of several sizes, with a user who owes nothing, cut the window
+    into runs whose fairness row (``fw_r``) differs from lane to lane.  A
+    commit keeps that row as it was: on 12 endpoints at these weights a
+    committed lane whose fairness term is dropped, or overwritten, takes
+    placements from its neighbours later in the run."""
+    tasks, eps, store, tm = _setup(n_per=6, replicas=3)
+    users = ("ann", "bob", "cy")
+    tasks = [TaskSpec(id=t.id, fn=t.fn, inputs=t.inputs,
+                      user=users[i % len(users)])
+             for i, t in enumerate(tasks)]
+    fw = FairnessWeights(debt={"ann": 0.3, "bob": 1.0}, mu=mu)
+    a = mhra(tasks, eps, store, tm, alpha=alpha, engine="soa", fairness=fw)
+    b = mhra(tasks, eps, store, tm, alpha=alpha, engine="jax", fairness=fw)
+    _assert_bitwise(a, b)
+
+
 @pytest.mark.parametrize("n_tasks", [1, 2, 31, 32, 63, 64, 65])
 def test_jax_matches_soa_window_sizes(n_tasks, monkeypatch):
     """Windows at and beside the power-of-two pad of the scan's step axis:
